@@ -1,7 +1,8 @@
 """Figure 6 analog: scale-out of the decomposable TC plan over 1..8 workers.
 
 Spawns subprocesses with forced host-device counts (the main process must
-keep 1 device).  The measured quantity on CPU hosts is *structural*: the work
+keep 1 device); they run on the host CPU by design, so they never contend
+with a parent for a chip.  The measured quantity on CPU hosts is *structural*: the work
 per worker shrinks with the shard count while the collective count stays at
 one scalar psum per iteration — wall-clock speedup on a single physical core
 is not expected, so the derived column reports per-worker row counts and the
@@ -51,11 +52,11 @@ def main() -> list[str]:
             [sys.executable, "-c", textwrap.dedent(SCRIPT.replace("{W}", str(w)))],
             capture_output=True, text=True, timeout=560,
             env={"XLA_FLAGS": f"--xla_force_host_platform_device_count={w}",
+                 "JAX_PLATFORMS": "cpu",
                  "PYTHONPATH": str(SRC), "PATH": "/usr/bin:/bin"})
         if proc.returncode != 0:
-            out.append(emit(f"fig6_scaleout_w{w}", 0.0, "ERROR"))
-            print(proc.stderr[-500:])
-            continue
+            raise RuntimeError(f"scale-out worker w={w} failed:\n"
+                               f"{proc.stderr[-2000:]}")
         rec = json.loads(proc.stdout.strip().splitlines()[-1])
         out.append(emit(
             f"fig6_scaleout_w{w}", rec["wall_s"],

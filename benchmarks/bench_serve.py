@@ -749,9 +749,39 @@ print("RESULT " + json.dumps(out))
 """
 
 
-def _durable_child(cfg: dict) -> tuple[dict, dict]:
-    """Run one restart (cold or warm) in a fresh interpreter; returns
-    (timing/recovery record, {answer-key: rows})."""
+# child script that populates the durable state: a service under the
+# max-durability cadence answers the batches, takes the appends, records the
+# answers it then serves, and dies without close() — the crash.  It runs in
+# its own interpreter so the benchmark's parent never holds the device while
+# a child needs it.
+_DURABLE_POPULATE = r"""
+import json, os, sys
+import numpy as np
+cfg = json.loads(sys.argv[1])
+from repro.service import DatalogService
+TC = "tc(X,Y) <- arc(X,Y).\ntc(X,Y) <- tc(X,Z), arc(Z,Y)."
+svc = DatalogService(TC, db={"arc": np.load(cfg["edb"])},
+                     durable_dir=cfg["durable_dir"], snapshot_every=1,
+                     result_cache=4096)
+batches = [[("tc", (int(s), None)) for s in bb] for bb in cfg["batches"]]
+for q in batches:
+    svc.ask_batch(list(q))
+for rows in cfg["appends"]:
+    svc.append("arc", np.asarray(rows, np.int64))
+svc._durable.wait()  # the auto-snapshot covers every append
+want = [svc.ask_batch(list(q)) for q in batches]
+np.savez(cfg["answers"], **{f"b{i}_{j}": np.asarray(a)
+                            for i, bb in enumerate(want)
+                            for j, a in enumerate(bb)})
+print("RESULT {}", flush=True)
+os._exit(0)  # crash: no close(), nothing was lost
+"""
+
+
+def _durable_child(cfg: dict, script: str = _DURABLE_CHILD
+                   ) -> tuple[dict, dict]:
+    """Run one child (populate, cold or warm restart) in a fresh
+    interpreter; returns (timing/recovery record, {answer-key: rows})."""
     import os
     import subprocess
     import sys
@@ -760,7 +790,7 @@ def _durable_child(cfg: dict) -> tuple[dict, dict]:
     src = str(Path(__file__).resolve().parent.parent / "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(
-        [sys.executable, "-c", _DURABLE_CHILD, json.dumps(cfg)],
+        [sys.executable, "-c", script, json.dumps(cfg)],
         capture_output=True, text=True, env=env, timeout=1800)
     if proc.returncode != 0:
         raise RuntimeError(f"durable child failed:\n{proc.stderr[-2000:]}")
@@ -778,7 +808,9 @@ def bench_durable(smoke: bool) -> dict:
     (``snapshot_every=1``: every append publishes a snapshot, so a crash
     loses nothing and recovery is pure snapshot restore), crash it, then
     race two REAL restarts — fresh interpreters, cold jit caches — to the
-    same batches of answers:
+    same batches of answers.  Populating runs in a child too: the parent
+    touches no device until every child has exited, so on a chip (one
+    process at a time) the children are not locked out:
 
     * **cold** — a fresh in-memory service over the final EDB: engine
       build + fixpoint compilation + every closure recomputed (what every
@@ -819,17 +851,6 @@ def bench_durable(smoke: bool) -> dict:
     work = tempfile.mkdtemp(prefix="bench_durable_")
     dur = str(Path(work) / "state")
     try:
-        # --- populate under snapshot_every=1, then crash -------------------
-        svc = DatalogService(TC, db={"arc": edges}, durable_dir=dur,
-                             snapshot_every=1, result_cache=4096)
-        for q in batches:
-            svc.ask_batch(list(q))
-        svc.append("arc", rows1)
-        svc.append("arc", rows2)  # auto-snapshot covers both appends
-        svc._durable.wait()
-        want = [svc.ask_batch(list(q)) for q in batches]
-        del svc  # crash: no close(), nothing was lost
-
         genesis = Path(work) / "genesis.npy"
         final = Path(work) / "final.npy"
         np.save(genesis, edges)
@@ -837,6 +858,14 @@ def bench_durable(smoke: bool) -> dict:
                 np.unique(np.concatenate([edges, rows1, rows2]), axis=0))
         src_lists = [[int(s) for s in srcs[i * b:(i + 1) * b]]
                      for i in range(nb)]
+
+        # --- populate under snapshot_every=1, then crash -------------------
+        _, want_ans = _durable_child(
+            {"edb": str(genesis), "batches": src_lists, "durable_dir": dur,
+             "appends": [rows1.tolist(), rows2.tolist()],
+             "answers": str(Path(work) / "want.npz")}, _DURABLE_POPULATE)
+        want = [[want_ans[f"b{i}_{j}"] for j in range(len(bb))]
+                for i, bb in enumerate(batches)]
 
         # --- the race: fresh-process cold rebuild vs warm restart ----------
         cold_out, cold_ans = _durable_child(

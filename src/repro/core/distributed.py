@@ -35,19 +35,18 @@ from .relation import EMPTY, hash32
 from .semiring import BOOL, MIN_PLUS, Semiring
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map moved out of jax.experimental at different versions;
-    accept both spellings (check_vma was called check_rep before)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
 # ---------------------------------------------------------------------------
 # Dense decomposable TC / SSSP (GPS = first argument)
 # ---------------------------------------------------------------------------
+
+
+def _changed(sr: Semiring, dn: jax.Array, d: jax.Array) -> jax.Array:
+    """1 when this shard's rows moved, as int32: the convergence test folds
+    to one bool per shard before the psum, so no 64-bit count of the (B, n)
+    rows is ever formed (the chip emulates 64-bit arithmetic)."""
+    moved = dn != d if sr.dtype == jnp.bool_ else \
+        ~((dn == d) | (jnp.isinf(dn) & jnp.isinf(d)))
+    return jnp.any(moved).astype(jnp.int32)
 
 
 def tc_decomposable(mesh, adj: jax.Array, axis: str = "data",
@@ -76,16 +75,14 @@ def tc_decomposable(mesh, adj: jax.Array, axis: str = "data",
             d, _, it = c
             upd = mm(d, arc_full)
             dn = sr.add(d, upd)
-            changed = jnp.sum(dn != d) if sr.dtype == jnp.bool_ else jnp.sum(
-                ~((dn == d) | (jnp.isinf(dn) & jnp.isinf(d))))
             # global convergence: the only collective in the loop
-            alive = jax.lax.psum(changed, axis) > 0
+            alive = jax.lax.psum(_changed(sr, dn, d), axis) > 0
             return dn, alive, it + 1
 
         d, _, it = jax.lax.while_loop(cond, body, (d_loc, jnp.array(True), jnp.int32(0)))
         return d, it
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body_fn, mesh=mesh,
         in_specs=(P(axis, None), P()),  # rows sharded; arc broadcast (Fig. 4)
         out_specs=(P(axis, None), P()),
@@ -122,6 +119,8 @@ def tc_frontier_decomposable(mesh, adj: jax.Array, frontier: jax.Array,
     return closed[:k], iters
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("mesh", "axis", "spmv", "max_iters"))
 def csr_frontier_decomposable(mesh, csr, frontier: jax.Array,
                               axis: str = "data", spmv=None,
                               max_iters: int | None = None):
@@ -133,6 +132,7 @@ def csr_frontier_decomposable(mesh, csr, frontier: jax.Array,
     runs its own O(|E|)-per-iteration segment fixpoint and the recursion
     stays shuffle-free — the only collective is the scalar convergence
     ``psum``.  Rows zero-pad to a multiple of the axis size and slice back.
+    Jitted with the mesh static, so warm batch shapes reuse one program.
     """
     from .sparse import csr_frontier_step
 
@@ -155,16 +155,15 @@ def csr_frontier_decomposable(mesh, csr, frontier: jax.Array,
             d, _, it = c
             upd = step(d, csr_full)
             dn = sr.add(d, upd)
-            changed = jnp.sum(dn != d) if sr.dtype == jnp.bool_ else jnp.sum(
-                ~((dn == d) | (jnp.isinf(dn) & jnp.isinf(d))))
-            alive = jax.lax.psum(changed, axis) > 0  # the only collective
+            # the only collective
+            alive = jax.lax.psum(_changed(sr, dn, d), axis) > 0
             return dn, alive, it + 1
 
         d, _, it = jax.lax.while_loop(
             cond, body, (f_loc, jnp.array(True), jnp.int32(0)))
         return d, it
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body_fn, mesh=mesh,
         in_specs=(P(axis, None), P()),  # rows sharded; packed arcs broadcast
         out_specs=(P(axis, None), P()),
@@ -237,7 +236,7 @@ def sg_allreduce(mesh, adj: jax.Array, axis: str = "data", max_iters: int | None
         s, _, it = jax.lax.while_loop(cond, body2, (sg_loc, jnp.array(True), jnp.int32(0)))
         return s, it
 
-    fn = _shard_map(body_fn, mesh=mesh, in_specs=P(axis, None),
+    fn = jax.shard_map(body_fn, mesh=mesh, in_specs=P(axis, None),
                        out_specs=(P(axis, None), P()), check_vma=False)
     return fn(adj)
 
@@ -342,7 +341,7 @@ def psn_shuffle_agg(
         keys, vals, _, _, _, it, ovf = jax.lax.while_loop(cond, body, init)
         return keys, vals, it, ovf
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body_fn, mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(), P()),

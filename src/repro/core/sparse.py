@@ -307,9 +307,8 @@ def _tile_plan(dst: np.ndarray, m: int, cap: int, n_alloc: int,
     to a :func:`quantize_rows` bucket by repeating the last item — safe
     because ⊕ is idempotent — so warm graphs reuse compiled grids.
     """
-    w = max(128, bn)  # the kernel wrapper's padded frontier width
-    n_pad = ((max(n_alloc, 1) + w - 1) // w) * w
-    nt, nchunks = n_pad // bn, cap // chunk
+    from ..kernels.spmv import padded_width
+    nt, nchunks = padded_width(n_alloc, bn) // bn, -(-cap // chunk)
     hits = np.zeros((nt, nchunks), bool)
     if m:
         hits[dst[:m] // bn, np.arange(m) // chunk] = True
@@ -329,10 +328,6 @@ def _tile_plan(dst: np.ndarray, m: int, cap: int, n_alloc: int,
             np.asarray(first, np.int32))
 
 
-def _pow2_floor(x: int) -> int:
-    return 1 << (max(int(x), 1).bit_length() - 1)
-
-
 def build_csr(edges: np.ndarray, n_alloc: int, kind: str = "bool",
               tail_min: int = 8, ell_cfg: tuple = DEFAULT_ELL_CFG,
               kernel_plan: tuple | None = None) -> CSRMatrix:
@@ -349,7 +344,8 @@ def build_csr(edges: np.ndarray, n_alloc: int, kind: str = "bool",
     ``ell_cfg=(floor, stride)`` sets the sliced-ELL capacity ladder
     (``stride=0`` = single-width legacy); ``kernel_plan=(chunk, bn)`` also
     precomputes the Pallas tile-skip worklist for those block sizes (the
-    autotuner's knobs — see ``kernels.autotune``).
+    autotuner's knobs — see ``kernels.autotune``), rounded to the blocks the
+    kernel can tile (``kernels.spmv.edge_chunk`` / ``column_tile``).
     """
     src, dst, val = _pack_edges(edges, kind)
     m = len(src)
@@ -367,9 +363,8 @@ def build_csr(edges: np.ndarray, n_alloc: int, kind: str = "bool",
     slices, rank = _sliced_ell_index(dst, m, n_alloc, cap - 1, tuple(ell_cfg))
     plan_cfg = plan = None
     if kernel_plan is not None:
-        chunk, bn = kernel_plan
-        chunk = min(_pow2_floor(chunk), cap)  # cap is a power of two
-        bn = _pow2_floor(bn)
+        from ..kernels.spmv import column_tile, edge_chunk
+        chunk, bn = edge_chunk(kernel_plan[0], cap), column_tile(kernel_plan[1])
         plan = _tile_plan(dst, m, cap, n_alloc, chunk, bn)
         plan_cfg = (chunk, bn)
     src = np.concatenate([src, np.zeros(pad, np.int32)])
@@ -463,48 +458,67 @@ def csr_append(csr: CSRMatrix, rows: np.ndarray,
 # O(B·(|E| + e_alloc)); every op is a dense gather/reduce the compiler
 # vectorizes, and e_alloc tracks |E| instead of n·max_indeg.
 
+#: widest single gather-⊕-reduce.  At batch widths of 16 and 32, XLA's TPU
+#: compiler takes time that grows with the reduced width (one 2^20-wide hub
+#: slice: ~150 s), so wider tables reduce in two stages of this width.
+#: At 2^20 vertices that cut the bool fixpoint's compile from ~180 s to
+#: ~11 s (v5e compiler, run on the host); the ⊕ is the same.
+REDUCE_WIDTH = 1024
+
+
+def _reduce_table(contrib: jax.Array, t, reduce) -> jax.Array:
+    """``reduce(contrib[:, t], axis=2)``: the (B, rows) ⊕ over each row of
+    packed positions ``t``, in two stages when ``t`` is wider than
+    :data:`REDUCE_WIDTH`."""
+    rows, w = t.shape
+    if w <= REDUCE_WIDTH or w % REDUCE_WIDTH:
+        return reduce(contrib[:, t], axis=2)
+    k = w // REDUCE_WIDTH
+    part = reduce(contrib[:, t.reshape(rows * k, REDUCE_WIDTH)], axis=2)
+    return reduce(part.reshape(contrib.shape[0], rows, k), axis=2)
+
 
 def _ell_step_or(f: jax.Array, src, val, ell) -> jax.Array:
     contrib = f[:, src] & val  # (B, cap): frontier value at each arc source
-    return jnp.any(contrib[:, ell], axis=2)  # (B, n, deg_cap) ⊕-reduce
+    return _reduce_table(contrib, ell, jnp.any)  # (B, n) over deg_cap
 
 
 def _ell_step_min(f: jax.Array, src, val, ell) -> jax.Array:
     contrib = f[:, src] + val  # +inf sentinels never win the min
-    return jnp.min(contrib[:, ell], axis=2)
+    return _reduce_table(contrib, ell, jnp.min)
 
 
 def _sliced_step_or(f: jax.Array, src, val, slices, rank) -> jax.Array:
     contrib = f[:, src] & val
-    parts = [jnp.any(contrib[:, t], axis=2) for t in slices]
+    parts = [_reduce_table(contrib, t, jnp.any) for t in slices]
     return jnp.concatenate(parts, axis=1)[:, rank]
 
 
 def _sliced_step_min(f: jax.Array, src, val, slices, rank) -> jax.Array:
     contrib = f[:, src] + val
-    parts = [jnp.min(contrib[:, t], axis=2) for t in slices]
+    parts = [_reduce_table(contrib, t, jnp.min) for t in slices]
     return jnp.concatenate(parts, axis=1)[:, rank]
 
 
 def _ell_step_max(f: jax.Array, src, val, ell) -> jax.Array:
     contrib = f[:, src] + val  # -inf sentinels never win the max
-    return jnp.max(contrib[:, ell], axis=2)
+    return _reduce_table(contrib, ell, jnp.max)
 
 
 def _sliced_step_max(f: jax.Array, src, val, slices, rank) -> jax.Array:
     contrib = f[:, src] + val
-    parts = [jnp.max(contrib[:, t], axis=2) for t in slices]
+    parts = [_reduce_table(contrib, t, jnp.max) for t in slices]
     return jnp.concatenate(parts, axis=1)[:, rank]
 
 
 def _ell_step_sum(f: jax.Array, src, val, ell) -> jax.Array:
     contrib = f[:, src] * val  # 0-valued sentinels contribute nothing
-    return jnp.sum(contrib[:, ell], axis=2)
+    return _reduce_table(contrib, ell, jnp.sum)
 
 
 def _sliced_step_sum(f: jax.Array, src, val, slices, rank) -> jax.Array:
     contrib = f[:, src] * val
-    parts = [jnp.sum(contrib[:, t], axis=2) for t in slices]
+    parts = [_reduce_table(contrib, t, jnp.sum) for t in slices]
     return jnp.concatenate(parts, axis=1)[:, rank]
 
 
@@ -567,18 +581,35 @@ def rows_from_sources(csr: CSRMatrix, srcs) -> jax.Array:
     one-hot frontier and take one segment step.  This is how the serving
     layer extracts batch seeds / append-resume deltas from a CSR relation.
     """
-    srcs = jnp.asarray(srcs, jnp.int32)
+    return _rows_from_sources_jit(csr, jnp.asarray(srcs, jnp.int32))
+
+
+@jax.jit
+def _rows_from_sources_jit(csr: CSRMatrix, srcs: jax.Array) -> jax.Array:
+    # one compiled program per (CSR buckets, batch width): run op by op,
+    # the seed step would materialize every (B, slice) gather at once
     b = srcs.shape[0]
     sr = csr.semiring
     onehot = jnp.full((b, csr.n_alloc), sr.zero, sr.dtype)
-    onehot = onehot.at[jnp.arange(b), srcs].set(sr.one)
-    step = csr_frontier_step(csr.kind)
-    return step(onehot, csr)
+    onehot = onehot.at[jnp.arange(b, dtype=jnp.int32), srcs].set(sr.one)
+    return csr_frontier_step(csr.kind)(onehot, csr)
 
 
 # ---------------------------------------------------------------------------
 # Semi-naive frontier fixpoints over CSR (twin of fixpoint_dense form=vector)
 # ---------------------------------------------------------------------------
+
+
+def count_generated(upd: jax.Array, zero) -> jax.Array:
+    """Cells of ``upd`` that are not the ⊕-zero, as a ``GEN_DTYPE`` scalar.
+
+    Counted per row in int32 first (a row of n < 2^31 cells cannot
+    overflow it), so the only 64-bit values are the B row counts: the chip
+    emulates 64-bit arithmetic, and a (B, n) table of it inside the
+    fixpoint loop would cost more than the step it counts."""
+    rows = jnp.sum(upd != jnp.asarray(zero, upd.dtype), axis=-1,
+                   dtype=jnp.int32)
+    return jnp.sum(rows.astype(GEN_DTYPE))
 
 
 def fixpoint_csr(csr: CSRMatrix, init: jax.Array, spmv: Callable | None = None,
@@ -609,7 +640,7 @@ def fixpoint_csr(csr: CSRMatrix, init: jax.Array, spmv: Callable | None = None,
         def abody(s):
             total, delta, it, gen = s
             new = step(delta, csr)
-            gen = gen + jnp.sum(new != sr.zero).astype(GEN_DTYPE)
+            gen = gen + count_generated(new, sr.zero)
             return total + new, new, it + 1, gen
 
         total, _, it, gen = jax.lax.while_loop(
@@ -627,7 +658,7 @@ def fixpoint_csr(csr: CSRMatrix, init: jax.Array, spmv: Callable | None = None,
         upd = step(dm, csr)
         Dn = sr.add(D, upd)
         changed = _ne(sr, Dn, D)
-        gen = gen + jnp.sum(upd != jnp.asarray(sr.zero, D.dtype)).astype(GEN_DTYPE)
+        gen = gen + count_generated(upd, sr.zero)
         new_mask = jnp.any(changed, axis=-1) if D.ndim > 1 else changed
         return Dn, new_mask, it + 1, gen
 
@@ -643,6 +674,12 @@ def _fixpoint_csr_jit(csr, init, spmv, max_iters):
     return fixpoint_csr(csr, init, spmv=spmv, max_iters=max_iters)
 
 
+def _default_max_iters(csr: CSRMatrix, init) -> int:
+    n = init.shape[-1]
+    return additive_max_iters(n) if not csr.semiring.idempotent \
+        else 4 * n + 8
+
+
 def fixpoint_csr_cached(csr: CSRMatrix, init: jax.Array,
                         spmv: Callable | None = None,
                         max_iters: int | None = None) -> DenseResult:
@@ -652,10 +689,19 @@ def fixpoint_csr_cached(csr: CSRMatrix, init: jax.Array,
     batches skip re-tracing.  ``spmv`` must be a module-level callable for a
     stable cache key."""
     if max_iters is None:
-        n = init.shape[-1]
-        max_iters = additive_max_iters(n) if not csr.semiring.idempotent \
-            else 4 * n + 8
+        max_iters = _default_max_iters(csr, init)
     return _fixpoint_csr_jit(csr, init, spmv, max_iters)
+
+
+def lower_fixpoint_csr(csr: CSRMatrix, init, spmv: Callable | None = None,
+                       max_iters: int | None = None):
+    """The ``jax.stages.Lowered`` program :func:`fixpoint_csr_cached` runs
+    for these arguments, which may be ``jax.ShapeDtypeStruct`` leaves.
+    ``.compile().memory_analysis()`` is what one launch needs on the device,
+    without running it."""
+    if max_iters is None:
+        max_iters = _default_max_iters(csr, init)
+    return _fixpoint_csr_jit.lower(csr, init, spmv, max_iters)
 
 
 # convenience front-ends (mirror the dense ones) ------------------------------

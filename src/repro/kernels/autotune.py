@@ -24,13 +24,21 @@ search, not a formula:
    is what ``explain()["kernels"]`` already reports, closing the loop the
    roofline attribution opened.
 
-Results cache per (graph-shape, kind) signature — degree-profile buckets,
-not exact graphs — so a serving tier rebuilding a relation after a tail
-fold reuses the tuned config unless the shape class actually moved.
-Pallas-kernel candidates (``use_kernel=True``) only enter the search on a
-TPU backend: under ``interpret=True`` the kernels are emulation, and timing
-emulation would steer the tuner off a cliff.  Pin a config
+Results cache per (graph-shape, kind) signature and launch width —
+degree-profile buckets, not exact graphs — so a serving tier rebuilding a
+relation after a tail fold reuses the tuned config unless the shape class
+actually moved.
+Pallas-kernel candidates (``use_kernel=True``) are timed only on a TPU
+backend: under ``interpret=True`` the kernels are emulation, and timing
+emulation would steer the tuner off a cliff.  A kernel candidate the kernel's
+own VMEM arithmetic (``kernels.spmv.vmem_bytes``) refuses at this width is
+left out too; either way the candidate stays in ``TuneResult.candidates``
+with ``measured_s=None`` and the reason in ``skipped``.  Pin a config
 (``DatalogService(tune=KernelConfig(...))``) to skip measurement entirely.
+
+Peak-relative figures (``predicted_s``, ``frac_peak_*``) use the running
+device's peaks by ``device_kind`` and are ``None`` on a kind without an
+entry, the CPU among them.
 """
 from __future__ import annotations
 
@@ -45,7 +53,8 @@ from ..core import sparse as _sparse
 from ..core.seminaive import quantize_ladder, quantize_rows
 from ..obs.roofline_attr import (achieved_fractions, csr_launch_cost,
                                  predicted_seconds)
-from ..roofline.report import V5E
+from ..roofline.report import default_peaks
+from .spmv import VMEM_LIMIT_BYTES, vmem_bytes
 
 __all__ = ["KernelConfig", "TuneResult", "autotune", "build_tuned",
            "graph_signature", "clear_cache", "DEFAULT_SLICE_CANDIDATES"]
@@ -58,7 +67,7 @@ class KernelConfig:
 
     slice_floor: int = 1  # sliced-ELL ladder floor (ell_cfg[0])
     slice_stride: int = 1  # ladder stride; 0 = single-width legacy ELL
-    chunk: int = 32  # Pallas edge-chunk block
+    chunk: int = 128  # Pallas edge-chunk block
     bn: int = 128  # Pallas column-tile block
     use_kernel: bool = False  # route the Pallas SpMV (with tile-skip plan)
 
@@ -82,7 +91,7 @@ SINGLE_WIDTH = KernelConfig(slice_floor=1, slice_stride=0)
 DEFAULT_SLICE_CANDIDATES = ((1, 1), (2, 1), (8, 1), (4, 2), (1, 0))
 
 #: Pallas block sizes tried when kernel candidates are in scope
-DEFAULT_BLOCK_CANDIDATES = ((32, 128), (64, 128), (32, 256))
+DEFAULT_BLOCK_CANDIDATES = ((128, 128), (256, 128), (128, 256))
 
 
 @dataclasses.dataclass
@@ -91,10 +100,11 @@ class TuneResult:
     gain: float  # baseline_seconds / best_seconds (>= 1 when tuning won)
     baseline_seconds: float
     best_seconds: float
-    frac_peak_flops: float  # achieved fraction of peak for USEFUL work
-    frac_peak_bw: float
+    frac_peak_flops: Optional[float]  # achieved fraction of peak for
+    frac_peak_bw: Optional[float]  # USEFUL work; None without peaks
     signature: tuple
-    candidates: list  # [{config, predicted_s, measured_s | None}, ...]
+    #: [{config, predicted_s, measured_s | None, skipped | None}, ...]
+    candidates: list
     cached: bool = False
 
     def as_dict(self) -> dict:
@@ -107,7 +117,8 @@ class TuneResult:
                 "candidates": [
                     {"config": c["config"].as_dict(),
                      "predicted_s": c["predicted_s"],
-                     "measured_s": c["measured_s"]}
+                     "measured_s": c["measured_s"],
+                     "skipped": c.get("skipped")}
                     for c in self.candidates]}
 
 
@@ -184,40 +195,66 @@ def autotune(edges: np.ndarray, n_alloc: int, kind: str, *, batch: int = 8,
              top_k: int = 2, include_kernels: Optional[bool] = None,
              slice_candidates: tuple = DEFAULT_SLICE_CANDIDATES,
              block_candidates: tuple = DEFAULT_BLOCK_CANDIDATES,
-             hw=V5E, use_cache: bool = True) -> TuneResult:
+             hw=None, use_cache: bool = True) -> TuneResult:
     """Pick a :class:`KernelConfig` for one relation by measured search.
 
-    ``include_kernels=None`` auto-gates Pallas candidates on the backend
-    (TPU only — interpret-mode timings are meaningless); ``batch`` sizes the
-    seed frontier the finalists are timed with.
+    ``include_kernels=None`` times Pallas candidates on a TPU backend only
+    (interpret-mode timings are meaningless) and lists them as skipped
+    elsewhere; ``False`` leaves them out.  ``batch`` is the widest batch the
+    relation will be launched at: the finalists are timed at that width,
+    and a kernel candidate whose VMEM at that width is over the scoped limit
+    is skipped.  ``hw`` overrides the device's peaks.
     """
     edges = np.asarray(edges, np.int64).reshape(-1, 2 if kind == "bool" else 3)
     sig = graph_signature(edges, n_alloc, kind)
-    if use_cache and sig in _CACHE:
-        return dataclasses.replace(_CACHE[sig], cached=True)
+    B = max(batch, 1)
+    if use_cache and (sig, B) in _CACHE:
+        return dataclasses.replace(_CACHE[sig, B], cached=True)
+    kernel_skip = None
     if include_kernels is None:
-        include_kernels = jax.default_backend() == "tpu"
+        include_kernels, backend = True, jax.default_backend()
+        if backend != "tpu":
+            kernel_skip = (f"backend {backend}: interpret-mode kernel "
+                           "timings are emulation, not a device measurement")
+    if hw is None:
+        hw = default_peaks()
     indeg = _indegree(edges, n_alloc)
     m = len(edges)
     itemsize = 1 if kind == "bool" else 4
-    B = max(batch, 1)
 
-    # -- 1. analytic seed: rank every layout by its roofline lower bound ----
+    # -- 1. analytic seed: rank every layout by its allocated segment slots
+    # (every term of the roofline cost grows with them, so this is the
+    # roofline ranking on any chip) ------------------------------------------
     ranked = []
     for ell_cfg in slice_candidates:
         e_alloc = _predicted_e_alloc(indeg, ell_cfg)
-        cost = csr_launch_cost(B, n_alloc, e_alloc, itemsize, iters=1)
         base = KernelConfig(slice_floor=ell_cfg[0], slice_stride=ell_cfg[1])
-        ranked.append((predicted_seconds(cost, hw), base))
+        ranked.append((e_alloc, base))
     ranked.sort(key=lambda t: t[0])
     shortlist = [cfg for _, cfg in ranked[:top_k]]
     if SINGLE_WIDTH not in shortlist:
         shortlist.append(SINGLE_WIDTH)  # the gain denominator always runs
+    skipped: dict = {}
     if include_kernels:
-        shortlist += [dataclasses.replace(shortlist[0], use_kernel=True,
-                                          chunk=c, bn=b)
-                      for c, b in block_candidates]
-    predicted = {cfg: p for p, cfg in ranked}
+        for c, b in block_candidates:
+            cfg = dataclasses.replace(shortlist[0], use_kernel=True,
+                                      chunk=c, bn=b)
+            need = vmem_bytes(kind, B, n_alloc, chunk=c, bn=b)
+            if need > VMEM_LIMIT_BYTES:  # refused on any backend
+                skipped[cfg] = (f"needs {need} bytes of VMEM at ({B}, "
+                                f"{n_alloc}), over the {VMEM_LIMIT_BYTES}-"
+                                "byte scoped limit")
+            elif kernel_skip is not None:
+                skipped[cfg] = kernel_skip
+            else:
+                shortlist.append(cfg)
+    e_allocs = {cfg.ell_cfg: e for e, cfg in ranked}
+    e_allocs.setdefault(SINGLE_WIDTH.ell_cfg,
+                        _predicted_e_alloc(indeg, SINGLE_WIDTH.ell_cfg))
+
+    def predicted(cfg):
+        return predicted_seconds(csr_launch_cost(
+            B, n_alloc, e_allocs[cfg.ell_cfg], itemsize, iters=1), hw)
 
     # -- 2./3. measure the shortlist, score by useful-work roofline fraction
     from . import ops as _kops  # local import: kernels.ops pulls every kernel
@@ -229,11 +266,15 @@ def autotune(edges: np.ndarray, n_alloc: int, kind: str, *, batch: int = 8,
         spmv = _kops.csr_frontier_step(kind) if cfg.use_kernel else None
         secs = _measure_fixpoint(csr, srcs, spmv)
         rows.append({"config": cfg, "measured_s": secs,
-                     "predicted_s": predicted.get(cfg)})
+                     "predicted_s": predicted(cfg), "skipped": None})
+    for cfg, why in skipped.items():
+        rows.append({"config": cfg, "measured_s": None,
+                     "predicted_s": predicted(cfg), "skipped": why})
     for _, cfg in ranked[top_k:]:  # report the pruned tail too
         if all(r["config"] != cfg for r in rows):
             rows.append({"config": cfg, "measured_s": None,
-                         "predicted_s": predicted.get(cfg)})
+                         "predicted_s": predicted(cfg),
+                         "skipped": "pruned by the analytic seed"})
     measured = [r for r in rows if r["measured_s"] is not None]
     best = min(measured, key=lambda r: r["measured_s"])
     baseline = next(r for r in measured if r["config"] == SINGLE_WIDTH)
@@ -247,5 +288,5 @@ def autotune(edges: np.ndarray, n_alloc: int, kind: str, *, batch: int = 8,
         frac_peak_bw=fr["frac_peak_bw"],
         signature=sig, candidates=rows)
     if use_cache:
-        _CACHE[sig] = res
+        _CACHE[sig, B] = res
     return res

@@ -32,7 +32,7 @@ def _rglru_kernel(a_ref, b_ref, o_ref, h_ref):
 
     def body(t, h):
         h = a[t] * h + b[t]
-        pl.store(o_ref, (0, pl.dslice(t, 1), slice(None)), h[None])
+        o_ref[0, pl.ds(t, 1), :] = h[None]
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, a.shape[0], body, h_ref[...])

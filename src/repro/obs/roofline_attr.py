@@ -7,7 +7,10 @@ serving layer's fixpoint launches to the seed ``roofline/`` hardware
 model (:class:`repro.roofline.report.HW`): every launch records an
 analytic flop/byte model for its kernel plus the measured
 launch→device-sync wall time, and ``report()`` emits achieved-vs-peak
-fractions and the dominant roofline term per kernel.
+fractions and the dominant roofline term per kernel.  The peaks are the
+running device's, keyed by ``device_kind``
+(:func:`repro.roofline.report.peaks_for`); on a kind with no entry (the CPU
+among them) every peak-relative figure is ``None``: not measured.
 
 Analytic cost models (per fixpoint *iteration*; B = padded batch rows,
 n = padded domain, e = allocated packed-arc slots incl. ELL padding):
@@ -28,7 +31,7 @@ import dataclasses
 import threading
 from typing import Any, Dict, Optional
 
-from ..roofline.report import HW, V5E
+from ..roofline.report import HW, default_peaks
 
 __all__ = ["KernelAttribution", "dense_launch_cost", "csr_launch_cost",
            "predicted_seconds", "achieved_fractions"]
@@ -56,20 +59,24 @@ def csr_launch_cost(B: int, n_alloc: int, e_alloc: int, itemsize: int,
     return {"flops": flops_per_iter * iters, "bytes": bytes_per_iter * iters}
 
 
-def predicted_seconds(cost: Dict[str, float], hw: HW = V5E) -> float:
+def predicted_seconds(cost: Dict[str, float],
+                      hw: Optional[HW]) -> Optional[float]:
     """Roofline lower bound for an analytic cost: the slower of its compute
-    and memory terms.  The autotuner (``kernels.autotune``) seeds its
-    measured search with this — candidates whose *allocated* work (e_alloc
-    padding included) predicts slower than the incumbent's bound are not
-    worth timing."""
+    and memory terms (``None`` without peaks).  The autotuner
+    (``kernels.autotune``) reports it beside each candidate's timing."""
+    if hw is None:
+        return None
     return max(cost["flops"] / hw.peak_flops, cost["bytes"] / hw.hbm_bw)
 
 
 def achieved_fractions(cost: Dict[str, float], seconds: float,
-                       hw: HW = V5E) -> Dict[str, float]:
+                       hw: Optional[HW]) -> Dict[str, Optional[float]]:
     """Achieved-vs-peak fractions for a measured run of an analytic cost —
-    the autotuner's scoring function (``cost`` holds *useful* work, so a
-    layout that shrinks padding raises the fraction at equal wall time)."""
+    the autotuner's report (``cost`` holds *useful* work, so a layout that
+    shrinks padding raises the fraction at equal wall time).  ``None``
+    fractions without peaks."""
+    if hw is None:
+        return {"frac_peak_flops": None, "frac_peak_bw": None}
     secs = max(seconds, 1e-12)
     return {"frac_peak_flops": cost["flops"] / secs / hw.peak_flops,
             "frac_peak_bw": cost["bytes"] / secs / hw.hbm_bw}
@@ -87,8 +94,8 @@ class _KernelTally:
 class KernelAttribution:
     """Thread-safe accumulator of per-kernel launch costs + timings."""
 
-    def __init__(self, hw: HW = V5E):
-        self.hw = hw
+    def __init__(self, hw: Optional[HW] = None):
+        self.hw = hw  # None: the running device's peaks, read at report()
         self._lock = threading.Lock()
         self._tallies: Dict[str, _KernelTally] = {}
 
@@ -106,18 +113,18 @@ class KernelAttribution:
             t.bytes += bytes
 
     def report(self) -> Dict[str, Dict[str, Any]]:
-        """Per-kernel achieved-vs-peak summary for ``explain()``."""
+        """Per-kernel achieved-vs-peak summary for ``explain()``; the
+        peak-relative keys are ``None`` on a device without peaks."""
         with self._lock:
             tallies = {k: dataclasses.replace(t)
                        for k, t in self._tallies.items()}
+        hw = self.hw if self.hw is not None else default_peaks()
         out: Dict[str, Dict[str, Any]] = {}
         for name, t in sorted(tallies.items()):
             secs = max(t.seconds, 1e-12)
             ach_flops = t.flops / secs
             ach_bw = t.bytes / secs
-            compute_s = t.flops / self.hw.peak_flops
-            memory_s = t.bytes / self.hw.hbm_bw
-            out[name] = {
+            row = {
                 "launches": t.launches,
                 "iterations": t.iterations,
                 "seconds": t.seconds,
@@ -125,10 +132,19 @@ class KernelAttribution:
                 "model_bytes": t.bytes,
                 "achieved_flops_per_s": ach_flops,
                 "achieved_bytes_per_s": ach_bw,
-                "frac_peak_flops": ach_flops / self.hw.peak_flops,
-                "frac_peak_bw": ach_bw / self.hw.hbm_bw,
-                "dominant": "compute" if compute_s >= memory_s else "memory",
+                "frac_peak_flops": None,
+                "frac_peak_bw": None,
+                "dominant": None,
             }
+            if hw is not None:
+                compute_s = t.flops / hw.peak_flops
+                memory_s = t.bytes / hw.hbm_bw
+                row.update(
+                    frac_peak_flops=ach_flops / hw.peak_flops,
+                    frac_peak_bw=ach_bw / hw.hbm_bw,
+                    dominant="compute" if compute_s >= memory_s
+                    else "memory")
+            out[name] = row
         return out
 
     def clear(self) -> None:

@@ -27,6 +27,23 @@ class HW:
 
 V5E = HW()
 
+#: peaks by ``jax.Device.device_kind``.  TPU v5e ("TPU v5 lite"): Google
+#: Cloud's "TPU v5e" page — 197 TFLOP/s bf16, 819 GB/s and 16 GB of HBM.
+PEAKS = {"TPU v5 lite": V5E}
+
+
+def peaks_for(device_kind: str) -> HW | None:
+    """The peaks of one chip kind, or None for a kind with no entry (the
+    CPU among them): a fraction of peak there is "not measured", never a
+    fraction of some other chip's peak."""
+    return PEAKS.get(device_kind)
+
+
+def default_peaks() -> HW | None:
+    """:func:`peaks_for` the device JAX places arrays on by default."""
+    import jax
+    return peaks_for(jax.devices()[0].device_kind)
+
 
 @dataclasses.dataclass
 class RooflineTerms:

@@ -87,10 +87,11 @@ class AsyncDatalogService:
     ``max_wait_ms``   the coalescing window: the dispatcher flushes when the
                       oldest waiting query has aged this much (or the window
                       filled).  Bounds the latency cost of batching.
-    ``max_batch``     flush size cap; also the natural knob to align with
-                      the service's ``batch_pads`` (a flush pads up to the
-                      next level, so ``max_batch`` = a pad level wastes no
-                      padding at full load).
+    ``max_batch``     flush size cap.  It becomes the service's widest
+                      batch pad (``DatalogService.cap_batch``), so flushes,
+                      append resumes and tuned kernels share one bound; it
+                      may not exceed the widest pad the service was built
+                      with.
     ``queue_depth``   admission bound on *waiting* (unflushed) queries;
                       beyond it submits shed with :class:`QueueFullError`.
     ``inflight``      launched-but-unfinalized batches allowed at once (2 =
@@ -114,6 +115,7 @@ class AsyncDatalogService:
         self.svc = service
         self.max_wait = max_wait_ms / 1000.0
         self.max_batch = max(1, int(max_batch))
+        service.cap_batch(self.max_batch)
         self.queue_depth = max(1, int(queue_depth))
         self.stats = AdmissionStats()
         self._fence = _inc.EpochFence()

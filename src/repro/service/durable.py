@@ -447,11 +447,11 @@ class DurabilityManager:
         self._ckpt.wait()
 
     def _prune(self, keep_from: int) -> None:
-        """Drop generations beyond ``keep_snapshots`` (published ones only —
-        the in-flight step publishes later as the newest)."""
-        for step in complete_steps(self.snap_dir)[self.keep_snapshots - 1:]:
-            if step >= keep_from:
-                continue
+        """Drop generations beyond ``keep_snapshots``, counting the step
+        ``keep_from`` just handed to the writer as the newest whether or not
+        the writer has published it yet."""
+        older = [s for s in complete_steps(self.snap_dir) if s < keep_from]
+        for step in older[self.keep_snapshots - 1:]:
             shutil.rmtree(self.snap_dir / f"step_{step:08d}",
                           ignore_errors=True)
 

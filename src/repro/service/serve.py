@@ -158,7 +158,8 @@ def main(argv: list[str] | None = None) -> int:
                     help="async coalescing window: flush when the oldest "
                          "waiting query has aged this much")
     ap.add_argument("--max-batch", type=int, default=64,
-                    help="async flush size cap")
+                    help="async flush size cap; also the widest batch "
+                         "pad, at most 128")
     ap.add_argument("--queue-depth", type=int, default=1024,
                     help="async admission bound; beyond it submits are shed "
                          "with QueueFullError")
@@ -194,6 +195,8 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--repl", action="store_true",
                     help="read queries/appends from stdin after the actions")
     args = ap.parse_args(argv)
+    from .. import enable_compile_cache
+    enable_compile_cache()
 
     program = TC_DEMO
     if args.program:

@@ -32,6 +32,7 @@ import threading
 import time
 from collections import OrderedDict
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -203,10 +204,14 @@ class _DenseRelation:
             self.flips += 1
             self.last_flip = f"{prev}->{now}"
 
-    def seed_rows(self, srcs) -> jnp.ndarray:
-        """The (B, n_alloc) frontier rows ``A[srcs]`` in the carrier."""
+    def seed_rows(self, srcs, pads: tuple[int, ...]) -> jnp.ndarray:
+        """The (B, n_alloc) frontier rows ``A[srcs]`` in the carrier.  A CSR
+        seed is built at the padded batch width, so it reuses the seed
+        program the cold batches of that width compiled."""
         if self.is_csr:
-            return _sparse.rows_from_sources(self.csr, srcs)
+            b = len(srcs)
+            idx = list(srcs) + [srcs[0]] * (_batch.pad_batch_size(b, pads) - b)
+            return _sparse.rows_from_sources(self.csr, idx)[:b]
         return self.matrix[jnp.asarray(srcs)]
 
     def run_batch(self, svc: "DatalogService", srcs: list[int], init=None):
@@ -862,6 +867,45 @@ class DatalogService:
         if self._durable is not None:
             self._durable.close()
 
+    def cap_batch(self, max_batch: int) -> None:
+        """Make ``max_batch`` the widest batch pad.  Every launch that the
+        service sizes itself is then at most that wide: admission flushes,
+        append resumes and the width kernels are tuned at.  Wider pads are
+        dropped; a ``max_batch`` wider than the widest pad raises
+        ``ValueError``, since relations may already be tuned below it."""
+        max_batch = int(max_batch)
+        with self.lock:
+            if not 1 <= max_batch <= self.batch_pads[-1]:
+                raise ValueError(
+                    f"max_batch {max_batch} is outside 1..{self.batch_pads[-1]}"
+                    f", the service's widest batch pad (batch_pads="
+                    f"{self.batch_pads})")
+            self.batch_pads = tuple(
+                p for p in self.batch_pads if p < max_batch) + (max_batch,)
+
+    def compile_batch(self, pred: str, batch: int):
+        """Compile, without running it, the closure fixpoint that a flush of
+        ``batch`` single-source ``pred`` queries launches on a CSR relation:
+        its current layout, its kernels and the padded batch width.  Returns
+        the ``jax.stages.Compiled``; its ``memory_analysis()`` is what one
+        such launch needs on the device, so a deployment can size the
+        admission ``max_batch`` before it serves.  Single-device launches
+        only (a dense relation's need is its n×n matrix)."""
+        with self.lock:
+            low = self._lowering(pred)
+            if low is None:
+                raise PlanError(f"{pred!r} has no batched frontier fixpoint")
+            ds = self._dense_state(pred)
+            if self.mesh is not None or not ds.is_csr:
+                raise ValueError(
+                    f"compile_batch sizes single-device CSR launches; {pred!r}"
+                    f" is {'mesh-sharded' if self.mesh is not None else 'dense'}")
+            bp = _batch.pad_batch_size(max(int(batch), 1), self.batch_pads)
+            init = jax.ShapeDtypeStruct((bp, ds.n_alloc), ds.sr.dtype)
+            lowered = _sparse.lower_fixpoint_csr(
+                ds.csr, init, spmv=self._spmv(low.kind, ds.csr))
+        return lowered.compile()
+
     def _resume_tuple_snapshots(self, rel: str) -> dict:
         """Resume batched tuple templates from their fixpoint snapshots and
         refresh the per-qid cache entries; returns {cache_key: entry} of the
@@ -1130,7 +1174,10 @@ class DatalogService:
         if isinstance(self.tune, _at.KernelConfig):
             ds.tuning = {"config": self.tune.as_dict(), "pinned": True}
             return self.tune
-        res = _at.autotune(edges, ds.n_alloc, ds.low.kind)
+        # tuned at the widest pad (``cap_batch``): a kernel whose VMEM that
+        # width refuses is never chosen
+        res = _at.autotune(edges, ds.n_alloc, ds.low.kind,
+                           batch=self.batch_pads[-1])
         ds.tuning = {**res.as_dict(), "pinned": False}
         return res.config
 
@@ -1192,14 +1239,21 @@ class DatalogService:
             cold_keys = {k for k, _ in cold}
             self.stats.dropped_cold += self.cache.drop_where(
                 lambda k, e: k in cold_keys)
-        if not entries:
-            return
+        # resume in launches of at most the widest batch pad: the cache can
+        # hold more closures than one fixpoint of that width fits on the
+        # device, and wider pads were never compiled
+        top = self.batch_pads[-1]
+        for lo in range(0, len(entries), top):
+            self._resume_dense(pred, ds, entries[lo:lo + top], grown)
+
+    def _resume_dense(self, pred: str, ds: _DenseRelation, entries: list,
+                      grown: bool):
         srcs = [e.src for _, e in entries]
         prev = jnp.stack([e.raw for _, e in entries])
         if grown:
             prev = _inc.pad_rows(prev, ds.n_alloc, ds.sr.zero)
         if ds.sr.idempotent:
-            seed = ds.seed_rows(srcs)
+            seed = ds.seed_rows(srcs, self.batch_pads)
             table = ds.run_batch(self, srcs,
                                  init=_inc.resume_init(ds.sr, prev, seed)).table
         elif not len(ds.last_delta):

@@ -200,3 +200,28 @@ def test_append_under_sustained_load_stays_consistent():
         got = front.ask(("tc", (s, None)), timeout=60)
         assert rows_set(got) == rows_set(eng.ask("tc", (s, None))), s
     front.close()
+
+
+def test_max_batch_bounds_every_launch_the_service_sizes():
+    """The front end's ``max_batch`` becomes the service's widest batch pad,
+    so append resumes launch no wider than a flush; a ``max_batch`` wider
+    than the widest pad is refused."""
+    svc = DatalogService(TC, db={"arc": EDGES}, default_cap=2048)
+    assert svc.batch_pads == (1, 8, 32, 128)
+    with pytest.raises(ValueError, match="widest batch pad"):
+        AsyncDatalogService(svc, max_batch=256, start=False)
+    front = AsyncDatalogService(svc, max_wait_ms=1.0, max_batch=4)
+    assert svc.batch_pads == (1, 4)
+    sources = list(range(9))
+    for s in sources:
+        front.ask(("tc", (s, None)), timeout=60)
+    before = svc.stats.dense_fixpoints
+    front.append("arc", [[8, 0]])
+    assert svc.stats.resumed_rows == len(sources)
+    assert svc.stats.dense_fixpoints - before == 3  # 4 + 4 + 1 rows
+    eng = Engine(TC, db={"arc": np.vstack([EDGES, [[8, 0]]])},
+                 default_cap=2048)
+    for s in sources:
+        got = front.ask(("tc", (s, None)), timeout=60)
+        assert rows_set(got) == rows_set(eng.ask("tc", (s, None))), s
+    front.close()

@@ -319,6 +319,98 @@ def test_autotune_pinned_and_measured():
     assert res2.cached and res2.config == res.config
 
 
+def test_autotune_records_skipped_kernel_candidates():
+    """Kernel candidates are never dropped in silence: off a TPU they are
+    listed as not measured, and at a width their VMEM arithmetic refuses
+    they are listed with the bytes they would need."""
+    from repro.data.graphs import powerlaw_graph
+    from repro.kernels import autotune as at
+    edges = powerlaw_graph(256, 1500, alpha=1.5, seed=4)
+    at.clear_cache()
+    res = at.autotune(edges, 256, "bool", use_cache=False)
+    kern = [c for c in res.candidates if c["config"].use_kernel]
+    assert kern and all(c["measured_s"] is None for c in kern)
+    assert all("backend cpu" in c["skipped"] for c in kern)
+    assert res.frac_peak_flops is None and res.frac_peak_bw is None
+    wide = 1 << 17  # (64, 2^17) bool outputs overflow the scoped VMEM
+    res = at.autotune(edges, wide, "bool", batch=64, include_kernels=True,
+                      use_cache=False)
+    kern = [c for c in res.candidates if c["config"].use_kernel]
+    assert kern and all(c["measured_s"] is None for c in kern)
+    assert all("bytes of VMEM" in c["skipped"] for c in kern)
+    assert not res.config.use_kernel
+
+
+def test_autotune_guards_kernels_at_the_launch_width():
+    """The VMEM guard uses the widest width the relation is launched at: a
+    bool kernel that fits 8 rows at 2^15 columns but not 128 rows is
+    skipped when tuning for 128, and the tuned service tunes for its widest
+    batch pad, which the admission ``max_batch`` sets."""
+    from repro.data.graphs import powerlaw_graph
+    from repro.kernels import autotune as at
+    from repro.kernels.spmv import VMEM_LIMIT_BYTES, vmem_bytes
+    from repro.service import AsyncDatalogService, DatalogService
+    n = 1 << 15
+    assert vmem_bytes("bool", 8, n) <= VMEM_LIMIT_BYTES \
+        < vmem_bytes("bool", 128, n)
+    arcs = np.concatenate([powerlaw_graph(256, 1500, alpha=1.5, seed=4),
+                           [[0, n - 1]]])
+    at.clear_cache()
+    res = at.autotune(arcs, n, "bool", batch=128, include_kernels=True,
+                      top_k=1)
+    kern = [c for c in res.candidates if c["config"].use_kernel]
+    assert kern and all(f"VMEM at (128, {n})" in c["skipped"] for c in kern)
+    assert not res.config.use_kernel
+    tc = "tc(X,Y) <- arc(X,Y).\ntc(X,Y) <- tc(X,Z), arc(Z,Y)."
+    # the service at 128 finds that search in the cache; at 8 it tunes anew
+    for max_batch, reason in ((128, f"VMEM at (128, {n})"),
+                              (8, "backend cpu")):
+        svc = DatalogService(tc, db={"arc": arcs}, sparse=True, tune=True)
+        front = AsyncDatalogService(svc, max_batch=max_batch)
+        front.ask(("tc", (0, None)), timeout=120)
+        front.close()
+        cands = svc.explain()["kernels"]["tuning"]["tc"]["candidates"]
+        kern = [c for c in cands if c["config"]["use_kernel"]]
+        assert kern and all(reason in c["skipped"] for c in kern), max_batch
+
+
+def test_autotune_without_single_width_candidate():
+    """Slice candidates that leave out the single-width layout still tune:
+    that layout is always measured as the gain's baseline."""
+    from repro.data.graphs import powerlaw_graph
+    from repro.kernels import autotune as at
+    edges = powerlaw_graph(256, 1500, alpha=1.5, seed=4)
+    res = at.autotune(edges, 256, "bool", include_kernels=False,
+                      slice_candidates=((2, 1), (1, 1)), use_cache=False)
+    base = [c for c in res.candidates if c["config"] == at.SINGLE_WIDTH]
+    assert len(base) == 1 and base[0]["measured_s"] is not None
+    assert res.gain > 0
+
+
+def test_pallas_service_refuses_width_over_vmem():
+    """``matmul='pallas'`` at a frontier width whose kernel the scoped VMEM
+    cannot hold raises the typed error naming the width and the bytes —
+    before Mosaic, and without falling back to the XLA path."""
+    from repro.kernels.spmv import VmemBudgetError, check_vmem, vmem_bytes
+    from repro.service import DatalogService
+    tc = "tc(X,Y) <- arc(X,Y).\ntc(X,Y) <- tc(X,Z), arc(Z,Y)."
+    n = 1 << 17  # 32 padded rows x 2^17 bool columns: 16 MiB of output
+    edges = np.array([[0, 1], [1, 2], [2, n - 1]], np.int64)
+    need = vmem_bytes("bool", 32, n)
+    with pytest.raises(VmemBudgetError) as err:
+        check_vmem("bool", 32, n)
+    assert err.value.width == n and err.value.need == need
+    assert str(n) in str(err.value) and str(need) in str(err.value)
+    svc = DatalogService(tc, db={"arc": edges}, sparse=True,
+                         matmul="pallas", bits=18)
+    burst = [("tc", (s, None)) for s in range(20)]  # pads to 32 rows
+    with pytest.raises(VmemBudgetError, match=str(need)):
+        svc.ask_batch(burst)
+    # narrow batches fit: the same service answers them through the kernel
+    assert {tuple(r) for r in svc.ask("tc", (0, None))} == \
+        {(0, 1), (0, 2), (0, n - 1)}
+
+
 # ---------------------------------------------------------------------------
 # additive (plus-times) and max-plus carriers (ROADMAP item 4)
 # ---------------------------------------------------------------------------

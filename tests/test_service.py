@@ -257,6 +257,27 @@ def test_append_resumes_dense_and_matches_fresh_engine():
     assert svc.cache.hits == hits0 + len(sources)
 
 
+@pytest.mark.parametrize("sparse", [True, False])
+def test_append_resume_runs_in_launches_of_the_widest_pad(sparse):
+    """More cached closures than the widest batch pad resume in several
+    launches of at most that width (a wider fixpoint was never compiled
+    and may not fit the device), with the same answers."""
+    edges = np.stack([np.arange(40), (np.arange(40) * 7 + 3) % 40], axis=1)
+    svc = DatalogService(TC, db={"arc": edges}, default_cap=2048,
+                         batch_pads=(1, 8), sparse=sparse)
+    sources = list(range(20))
+    svc.ask_batch([("tc", (s, None)) for s in sources])
+    before = svc.stats.dense_fixpoints
+    svc.append("arc", [[3, 39], [39, 0]])
+    assert svc.stats.resumed_rows == len(sources)
+    assert svc.stats.dense_fixpoints - before == 3  # 8 + 8 + 4 rows
+    eng = Engine(TC, db={"arc": np.concatenate([edges, [[3, 39], [39, 0]]])},
+                 default_cap=2048)
+    for s in sources:
+        assert rows_set(svc.ask("tc", (s, None))) == \
+            rows_set(eng.ask("tc", (s, None))), s
+
+
 def test_append_grows_domain_past_allocation():
     svc = DatalogService(TC, db={"arc": EDGES}, default_cap=2048)
     svc.ask("tc", (0, None))
