@@ -1,0 +1,10 @@
+"""Puts the repository root and ``src`` on the path, so the benchmark's
+tests import ``bench.lib`` and the program as ``run.py`` does."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+for p in (os.path.join(ROOT, "src"), ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
