@@ -20,7 +20,7 @@ MODULES = [
     "repro.service.admission", "repro.service.durable",
     "repro.checkpoint", "repro.checkpoint.store",
     "repro.obs", "repro.obs.trace", "repro.obs.metrics",
-    "repro.obs.fixpoint_probe", "repro.obs.roofline_attr",
+    "repro.obs.roofline_attr",
     "repro.kernels", "repro.kernels.autotune", "repro.data.graphs",
 ]
 for m in MODULES:

@@ -58,7 +58,6 @@ from . import sparse as _sparse
 from .seminaive import (Bindings, EdbIndex, build_edb_index, join_edb,
                         join_idb_prefix, pack_warm_rows, quantize_rows,
                         reachable_from_dense, single_source_distances_dense)
-from .semiring import BOOL, MIN_PLUS
 
 
 class CapacityError(RuntimeError):
@@ -591,8 +590,7 @@ class Engine:
         return out
 
     def ask_dense(self, pred: str, args: tuple, matmul=None,
-                  sparse: bool | None = None, spmv=None,
-                  probe: bool = False):
+                  sparse: bool | None = None, spmv=None):
         """Single-source fast path: lower a magic-restricted *decomposable*
         program onto a frontier semiring fixpoint seeded with the query
         frontier row (the dense analog of ``tc_decomposable``).
@@ -607,11 +605,6 @@ class Engine:
         ``PlanOptions.sparse``) forces a representation; ``None`` lets the
         density heuristic pick.  ``matmul`` overrides the dense ⊗, ``spmv``
         the sparse segment step.
-
-        ``probe=True`` runs the probed fixpoint twin
-        (``repro.obs.fixpoint_probe``) instead — the answer is bit-identical
-        — and returns ``(answer, FixpointProbe)`` with the per-iteration
-        frontier sizes and semi-naive Δ-fact counts.
         """
         low = detect_frontier_lowering(self.source_program, pred)
         q = as_query_literal((pred, args))
@@ -625,7 +618,7 @@ class Engine:
             rows = np.zeros((0, 2), np.int64)
             out = rows if low.kind == "bool" else (rows,
                                                    np.zeros((0,), np.int64))
-            return (out, None) if probe else out
+            return out
         n = max(int(edges[:, :2].max()) + 1, src + 1)
         opts = self.plan.options
         use_csr = opts.sparse if sparse is None else sparse
@@ -634,9 +627,6 @@ class Engine:
                 len(edges), n,
                 opts.sparse_threshold if opts.sparse_threshold is not None
                 else _sparse.DEFAULT_SPARSE_THRESHOLD)
-        pr = None
-        if probe:  # local import keeps core import-independent of obs
-            from ..obs import fixpoint_probe as _probe
         if use_csr:
             if opts.tune:  # local import keeps core import-light
                 from ..kernels import autotune as _at
@@ -649,32 +639,18 @@ class Engine:
             else:
                 csr = _sparse.build_csr(edges, n, low.kind)
             init = _sparse.rows_from_sources(csr, [src])
-            if probe:
-                res, pr = _probe.fixpoint_csr_probed(csr, init, spmv=spmv)
-            else:
-                res = _sparse.fixpoint_csr_cached(csr, init, spmv=spmv)
+            res = _sparse.fixpoint_csr_cached(csr, init, spmv=spmv)
             row = np.asarray(res.table[0])
         elif low.kind == "bool":
             adj = np.zeros((n, n), bool)
             adj[edges[:, 0], edges[:, 1]] = True
-            if probe:
-                res, pr = _probe.fixpoint_dense_probed(
-                    BOOL, jnp.asarray(adj), jnp.asarray(adj[src]),
-                    matmul=matmul)
-            else:
-                res = reachable_from_dense(jnp.asarray(adj), src,
-                                           matmul=matmul)
+            res = reachable_from_dense(jnp.asarray(adj), src, matmul=matmul)
             row = np.asarray(res.table)
         else:
             w = np.full((n, n), np.inf, np.float32)
             np.minimum.at(w, (edges[:, 0], edges[:, 1]), edges[:, 2].astype(np.float32))
-            if probe:
-                res, pr = _probe.fixpoint_dense_probed(
-                    MIN_PLUS, jnp.asarray(w), jnp.asarray(w[src]),
-                    matmul=matmul)
-            else:
-                res = single_source_distances_dense(jnp.asarray(w), src,
-                                                    matmul=matmul)
+            res = single_source_distances_dense(jnp.asarray(w), src,
+                                                matmul=matmul)
             row = np.asarray(res.table)
         if low.kind == "bool":
             dst = np.nonzero(row[:n])[0]
@@ -687,7 +663,7 @@ class Engine:
             out = (rows, row[dst].astype(np.int64))
         self.stats[f"{pred}__{'csr' if use_csr else 'dense'}"] = GroupStats(
             iterations=int(res.iterations), generated=int(res.generated))
-        return (out, pr) if probe else out
+        return out
 
     def ask_batch(self, queries: list | None = None, verify: bool = False,
                   caps: dict[str, int] | None = None,

@@ -59,7 +59,7 @@ def trace_count() -> int:
 
 #: generated-fact accumulator dtype.  ``jnp.int64`` is a silent int32 under
 #: default config (no ``jax_enable_x64``), so spell out the dtype that will
-#: actually exist and let the probe layer assert no-overflow against it.
+#: actually exist, and check counters against its real bound (``GEN_MAX``).
 GEN_DTYPE = jnp.asarray(0, jnp.int64).dtype
 GEN_MAX = jnp.iinfo(GEN_DTYPE).max
 
@@ -72,9 +72,22 @@ GEN_MAX = jnp.iinfo(GEN_DTYPE).max
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class DenseResult:
+    """A frontier fixpoint's table and the counters its loop carries.
+
+    ``active_rows`` and ``delta_facts`` are -1 where the loop did not count
+    them (the mesh-sharded fixpoint); readers skip -1."""
+
     table: jax.Array  # fixpoint matrix / vector
     iterations: jax.Array  # () int32
-    generated: jax.Array  # () int64 — facts produced before dedup (Tables 7/8)
+    generated: jax.Array  # () GEN_DTYPE — facts produced before dedup
+    #: () int32 — Σ over iterations of the rows still unconverged entering
+    #: each step, pad rows included (entries, for an (n,) vector, which
+    #: masks per entry); the accumulate form counts rows whose Δ is non-zero
+    active_rows: jax.Array | int = -1
+    #: () GEN_DTYPE — Σ over iterations of the table entries each step
+    #: changed (semi-naive Δ); the accumulate form counts each new Δ's
+    #: non-zero entries, which equals ``generated``
+    delta_facts: jax.Array | int = -1
 
 
 def _ne(sr: Semiring, a, b):
@@ -82,6 +95,82 @@ def _ne(sr: Semiring, a, b):
         return a != b
     # inf-aware compare for tropical semirings
     return ~((a == b) | (jnp.isinf(a) & jnp.isinf(b) & (jnp.sign(a) == jnp.sign(b))))
+
+
+def _row_counts(x: jax.Array, zero) -> jax.Array:
+    """Per-row int32 counts of the cells of ``x`` that are not ``zero``.
+
+    A row of n < 2^31 cells cannot overflow int32, so the only 64-bit
+    values the counters need are the B row counts (:func:`_sum_counts`):
+    the chip emulates 64-bit arithmetic, and a (B, n) table of it inside
+    the fixpoint loop would cost more than the step it counts."""
+    return jnp.sum(x != jnp.asarray(zero, x.dtype), axis=-1, dtype=jnp.int32)
+
+
+def _sum_counts(counts: jax.Array) -> jax.Array:
+    """Sum of per-row counts as a ``GEN_DTYPE`` scalar."""
+    return jnp.sum(counts.astype(GEN_DTYPE))
+
+
+def masked_fixpoint(sr: Semiring, init: jax.Array, step: Callable,
+                    max_iters: int) -> DenseResult:
+    """The idempotent semi-naive loop of every masked form, dense or CSR:
+    ``D <- D ⊕ step(D, mask)`` until no row changes.
+
+    ``step(D, mask)`` applies the form's Δ masking itself.  The change test
+    counts changed entries per row once: their sum is the Δ counter and
+    ``counts > 0`` the next mask, so the counters take no extra pass over
+    the table."""
+    zero = jnp.asarray(sr.zero, init.dtype)
+
+    def cond(s):
+        D, mask, it, gen, act, delta = s
+        return jnp.any(mask) & (it < max_iters)
+
+    def body(s):
+        D, mask, it, gen, act, delta = s
+        upd = step(D, mask)
+        with jax.named_scope("fixpoint_converge"):
+            Dn = sr.add(D, upd)
+            changed = _ne(sr, Dn, D)
+            counts = (jnp.sum(changed, axis=-1, dtype=jnp.int32)
+                      if D.ndim > 1 else changed.astype(jnp.int32))
+            gen = gen + _sum_counts(_row_counts(upd, zero))
+            act = act + jnp.sum(mask, dtype=jnp.int32)
+            delta = delta + _sum_counts(counts)
+        return Dn, counts > 0, it + 1, gen, act, delta
+
+    mask0 = jnp.ones(init.shape[:-1] if init.ndim > 1 else init.shape, bool)
+    D, _, it, gen, act, delta = jax.lax.while_loop(
+        cond, body, (init, mask0, jnp.int32(0), jnp.zeros((), GEN_DTYPE),
+                     jnp.int32(0), jnp.zeros((), GEN_DTYPE)))
+    return DenseResult(D, it, gen, act, delta)
+
+
+def accumulate_fixpoint(sr: Semiring, init: jax.Array, step: Callable,
+                        max_iters: int) -> DenseResult:
+    """The additive loop: ``C = Σ Δ``, ``Δ <- step(Δ)`` until Δ drains or
+    ``max_iters`` (which the host checks: :func:`check_additive_converged`).
+    The per-row counts of each new Δ give the generated and Δ counters and
+    the next test for a live row."""
+    zero = jnp.asarray(sr.zero, init.dtype)
+
+    def cond(s):
+        total, delta, live, it, gen, act = s
+        return jnp.any(live > 0) & (it < max_iters)
+
+    def body(s):
+        total, delta, live, it, gen, act = s
+        new = step(delta)
+        counts = _row_counts(new, zero)
+        act = act + jnp.sum(live > 0, dtype=jnp.int32)
+        gen = gen + _sum_counts(counts)
+        return total + new, new, counts, it + 1, gen, act
+
+    total, _, _, it, gen, act = jax.lax.while_loop(
+        cond, body, (init, init, _row_counts(init, zero), jnp.int32(0),
+                     jnp.zeros((), GEN_DTYPE), jnp.int32(0)))
+    return DenseResult(total, it, gen, act, gen)
 
 
 def fixpoint_dense(
@@ -116,58 +205,27 @@ def fixpoint_dense(
     if form == "accumulate":
         if sr.idempotent:
             raise ValueError("accumulate form is for additive semirings")
-
-        def cond(s):
-            total, delta, it, gen = s
-            return jnp.any(delta != sr.zero) & (it < max_iters)
-
-        def body(s):
-            total, delta, it, gen = s
-            new = mm(delta, arc)
-            gen = gen + jnp.sum(new != sr.zero).astype(GEN_DTYPE)
-            return total + new, new, it + 1, gen
-
-        total, _, it, gen = jax.lax.while_loop(
-            cond, body, (init, init, jnp.int32(0), jnp.zeros((), GEN_DTYPE))
-        )
-        return DenseResult(total, it, gen)
+        return accumulate_fixpoint(sr, init, lambda d: mm(d, arc), max_iters)
 
     def step(D, mask):
         if form == "linear":
             Dm = jnp.where(mask[:, None], D, jnp.asarray(sr.zero, D.dtype))
-            upd = mm(Dm, arc)
-        elif form == "nonlinear":
+            return mm(Dm, arc)
+        if form == "nonlinear":
             # semi-naive for nonlinear: Δ⊗D ⊕ D⊗Δ (symbolically rewritten r5)
             Dm = jnp.where(mask[:, None], D, jnp.asarray(sr.zero, D.dtype))
-            upd = sr.add(mm(Dm, D), mm(D, Dm))
-        elif form == "vector":
+            return sr.add(mm(Dm, D), mm(D, Dm))
+        if form == "vector":
             # batched (B, n) frontiers mask converged *rows*, not elements
             rmask = mask if D.ndim == 1 else mask[:, None]
             dm = jnp.where(rmask, D, jnp.asarray(sr.zero, D.dtype))
-            upd = mm(dm[None, :], arc)[0] if D.ndim == 1 else mm(dm, arc)
-        elif form == "sandwich":
+            return mm(dm[None, :], arc)[0] if D.ndim == 1 else mm(dm, arc)
+        if form == "sandwich":
             Dm = jnp.where(mask[:, None], D, jnp.asarray(sr.zero, D.dtype))
-            upd = mm(_transpose_arc(sr, arc), mm(Dm, arc))
-        else:
-            raise ValueError(form)
-        return sr.add(D, upd), upd
+            return mm(_transpose_arc(sr, arc), mm(Dm, arc))
+        raise ValueError(form)
 
-    def cond(s):
-        D, mask, it, gen = s
-        return jnp.any(mask) & (it < max_iters)
-
-    def body(s):
-        D, mask, it, gen = s
-        Dn, upd = step(D, mask)
-        changed = _ne(sr, Dn, D)
-        gen = gen + jnp.sum(upd != jnp.asarray(sr.zero, D.dtype)).astype(GEN_DTYPE)
-        new_mask = jnp.any(changed, axis=-1) if D.ndim > 1 else changed
-        return Dn, new_mask, it + 1, gen
-
-    mask0 = jnp.ones(init.shape[:-1] if init.ndim > 1 else init.shape, bool)
-    D, mask, it, gen = jax.lax.while_loop(
-        cond, body, (init, mask0, jnp.int32(0), jnp.zeros((), GEN_DTYPE)))
-    return DenseResult(D, it, gen)
+    return masked_fixpoint(sr, init, step, max_iters)
 
 
 def _transpose_arc(sr: Semiring, arc: jax.Array) -> jax.Array:

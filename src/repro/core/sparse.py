@@ -66,9 +66,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .semiring import Semiring, carrier_for
-from .seminaive import (GEN_DTYPE, DenseResult, _ne, additive_max_iters,
+from .seminaive import (DenseResult, accumulate_fixpoint, additive_max_iters,
                         bump_trace_count, check_additive_converged,
-                        quantize_ladder, quantize_rows)
+                        masked_fixpoint, quantize_ladder, quantize_rows)
 
 #: density |E|/n² below which the serving layer prefers CSR over the dense
 #: matrix (the auto heuristic; PlanOptions.sparse / DatalogService(sparse=)
@@ -159,7 +159,7 @@ class CSRMatrix:
     @property
     def e_alloc(self) -> int:
         """Allocated segment-reduce slots (sliced spine + tail): the ELL
-        padding overhead the roofline attribution charges per iteration."""
+        padding overhead the autotuner's cost model charges per iteration."""
         spine = sum(int(t.shape[0]) * int(t.shape[1]) for t in self.ell_slices)
         return spine + int(np.prod(self.tail_ell.shape))
 
@@ -590,26 +590,15 @@ def _rows_from_sources_jit(csr: CSRMatrix, srcs: jax.Array) -> jax.Array:
     # the seed step would materialize every (B, slice) gather at once
     b = srcs.shape[0]
     sr = csr.semiring
-    onehot = jnp.full((b, csr.n_alloc), sr.zero, sr.dtype)
-    onehot = onehot.at[jnp.arange(b, dtype=jnp.int32), srcs].set(sr.one)
-    return csr_frontier_step(csr.kind)(onehot, csr)
+    with jax.named_scope("csr_seed"):
+        onehot = jnp.full((b, csr.n_alloc), sr.zero, sr.dtype)
+        onehot = onehot.at[jnp.arange(b, dtype=jnp.int32), srcs].set(sr.one)
+        return csr_frontier_step(csr.kind)(onehot, csr)
 
 
 # ---------------------------------------------------------------------------
 # Semi-naive frontier fixpoints over CSR (twin of fixpoint_dense form=vector)
 # ---------------------------------------------------------------------------
-
-
-def count_generated(upd: jax.Array, zero) -> jax.Array:
-    """Cells of ``upd`` that are not the ⊕-zero, as a ``GEN_DTYPE`` scalar.
-
-    Counted per row in int32 first (a row of n < 2^31 cells cannot
-    overflow it), so the only 64-bit values are the B row counts: the chip
-    emulates 64-bit arithmetic, and a (B, n) table of it inside the
-    fixpoint loop would cost more than the step it counts."""
-    rows = jnp.sum(upd != jnp.asarray(zero, upd.dtype), axis=-1,
-                   dtype=jnp.int32)
-    return jnp.sum(rows.astype(GEN_DTYPE))
 
 
 def fixpoint_csr(csr: CSRMatrix, init: jax.Array, spmv: Callable | None = None,
@@ -619,8 +608,9 @@ def fixpoint_csr(csr: CSRMatrix, init: jax.Array, spmv: Callable | None = None,
     Twin of ``fixpoint_dense(form="vector")`` over the packed arcs: ``init``
     is an (n_alloc,) or batched (B, n_alloc) frontier in the carrier; rows
     that converge drop out of the next segment step via the same per-row
-    masking.  Returns the same :class:`DenseResult` so callers (the serving
-    batcher, ``Engine.ask_dense``) swap representations freely.
+    masking, and the same loop counts active rows and Δ entries.  Returns
+    the same :class:`DenseResult` so callers (the serving batcher,
+    ``Engine.ask_dense``) swap representations freely.
     """
     sr = csr.semiring
     step = spmv or csr_frontier_step(csr.kind)
@@ -628,44 +618,22 @@ def fixpoint_csr(csr: CSRMatrix, init: jax.Array, spmv: Callable | None = None,
     if max_iters is None:
         max_iters = additive_max_iters(n) if not sr.idempotent else 4 * n + 8
 
+    def segment_step(d):
+        with jax.named_scope("csr_segment_step"):
+            return step(d, csr)
+
     if not sr.idempotent:
         # accumulate form (twin of fixpoint_dense form="accumulate"): the
         # idempotent convergence test is meaningless for additive ⊕, so the
         # delta propagates until it drains — bounded by max_iters, which the
         # host checks afterwards (check_additive_converged)
-        def acond(s):
-            total, delta, it, gen = s
-            return jnp.any(delta != sr.zero) & (it < max_iters)
+        return accumulate_fixpoint(sr, init, segment_step, max_iters)
 
-        def abody(s):
-            total, delta, it, gen = s
-            new = step(delta, csr)
-            gen = gen + count_generated(new, sr.zero)
-            return total + new, new, it + 1, gen
-
-        total, _, it, gen = jax.lax.while_loop(
-            acond, abody, (init, init, jnp.int32(0), jnp.zeros((), GEN_DTYPE)))
-        return DenseResult(total, it, gen)
-
-    def cond(s):
-        D, mask, it, gen = s
-        return jnp.any(mask) & (it < max_iters)
-
-    def body(s):
-        D, mask, it, gen = s
+    def masked_step(D, mask):
         rmask = mask if D.ndim == 1 else mask[:, None]
-        dm = jnp.where(rmask, D, jnp.asarray(sr.zero, D.dtype))
-        upd = step(dm, csr)
-        Dn = sr.add(D, upd)
-        changed = _ne(sr, Dn, D)
-        gen = gen + count_generated(upd, sr.zero)
-        new_mask = jnp.any(changed, axis=-1) if D.ndim > 1 else changed
-        return Dn, new_mask, it + 1, gen
+        return segment_step(jnp.where(rmask, D, jnp.asarray(sr.zero, D.dtype)))
 
-    mask0 = jnp.ones(init.shape[:-1] if init.ndim > 1 else init.shape, bool)
-    D, mask, it, gen = jax.lax.while_loop(
-        cond, body, (init, mask0, jnp.int32(0), jnp.zeros((), GEN_DTYPE)))
-    return DenseResult(D, it, gen)
+    return masked_fixpoint(sr, init, masked_step, max_iters)
 
 
 @functools.partial(jax.jit, static_argnames=("spmv", "max_iters"))

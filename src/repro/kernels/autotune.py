@@ -20,9 +20,8 @@ search, not a formula:
    by a warmup run) on a seed batch.
 3. **Score by achieved-vs-peak.**  The score is the roofline fraction of
    *useful* work (2·B·|E| semiring ops against live arcs) — maximizing it
-   is minimizing wall time, but the number is comparable across layouts and
-   is what ``explain()["kernels"]`` already reports, closing the loop the
-   roofline attribution opened.
+   is minimizing wall time, but the number is comparable across layouts;
+   ``explain()["kernels"]["tuning"]`` reports it per tuned predicate.
 
 Results cache per (graph-shape, kind) signature and launch width —
 degree-profile buckets, not exact graphs — so a serving tier rebuilding a

@@ -1,16 +1,10 @@
-"""Roofline attribution for serving-path kernel launches.
+"""Analytic roofline cost of fixpoint launches, for the kernel autotuner.
 
-ROADMAP item 6 (sliced-ELL, prefetch maps, autotuning) needs each kernel
-change to be *attributable*: did the SpMV get closer to the memory roof,
-or is the frontier matmul still compute-bound? This module hooks the
-serving layer's fixpoint launches to the seed ``roofline/`` hardware
-model (:class:`repro.roofline.report.HW`): every launch records an
-analytic flop/byte model for its kernel plus the measured
-launch→device-sync wall time, and ``report()`` emits achieved-vs-peak
-fractions and the dominant roofline term per kernel.  The peaks are the
-running device's, keyed by ``device_kind``
-(:func:`repro.roofline.report.peaks_for`); on a kind with no entry (the CPU
-among them) every peak-relative figure is ``None``: not measured.
+``kernels.autotune`` prices each candidate layout with the flop/byte model
+below and reports its measured time against the running device's peaks
+(:class:`repro.roofline.report.HW`, keyed by ``device_kind``); on a kind
+with no entry (the CPU among them) every peak-relative figure is ``None``:
+not measured.
 
 Analytic cost models (per fixpoint *iteration*; B = padded batch rows,
 n = padded domain, e = allocated packed-arc slots incl. ELL padding):
@@ -27,14 +21,12 @@ rows, not a measurement bug.
 """
 from __future__ import annotations
 
-import dataclasses
-import threading
-from typing import Any, Dict, Optional
+from typing import Dict, Optional
 
-from ..roofline.report import HW, default_peaks
+from ..roofline.report import HW
 
-__all__ = ["KernelAttribution", "dense_launch_cost", "csr_launch_cost",
-           "predicted_seconds", "achieved_fractions"]
+__all__ = ["dense_launch_cost", "csr_launch_cost", "predicted_seconds",
+           "achieved_fractions"]
 
 
 def dense_launch_cost(B: int, n: int, itemsize: int, iters: int
@@ -80,73 +72,3 @@ def achieved_fractions(cost: Dict[str, float], seconds: float,
     secs = max(seconds, 1e-12)
     return {"frac_peak_flops": cost["flops"] / secs / hw.peak_flops,
             "frac_peak_bw": cost["bytes"] / secs / hw.hbm_bw}
-
-
-@dataclasses.dataclass
-class _KernelTally:
-    launches: int = 0
-    iterations: int = 0
-    seconds: float = 0.0
-    flops: float = 0.0
-    bytes: float = 0.0
-
-
-class KernelAttribution:
-    """Thread-safe accumulator of per-kernel launch costs + timings."""
-
-    def __init__(self, hw: Optional[HW] = None):
-        self.hw = hw  # None: the running device's peaks, read at report()
-        self._lock = threading.Lock()
-        self._tallies: Dict[str, _KernelTally] = {}
-
-    def record(self, kernel: str, *, seconds: float, iterations: int,
-               flops: float, bytes: float) -> None:
-        """One launch: analytic cost + measured launch→sync wall time."""
-        with self._lock:
-            t = self._tallies.get(kernel)
-            if t is None:
-                t = self._tallies[kernel] = _KernelTally()
-            t.launches += 1
-            t.iterations += iterations
-            t.seconds += seconds
-            t.flops += flops
-            t.bytes += bytes
-
-    def report(self) -> Dict[str, Dict[str, Any]]:
-        """Per-kernel achieved-vs-peak summary for ``explain()``; the
-        peak-relative keys are ``None`` on a device without peaks."""
-        with self._lock:
-            tallies = {k: dataclasses.replace(t)
-                       for k, t in self._tallies.items()}
-        hw = self.hw if self.hw is not None else default_peaks()
-        out: Dict[str, Dict[str, Any]] = {}
-        for name, t in sorted(tallies.items()):
-            secs = max(t.seconds, 1e-12)
-            ach_flops = t.flops / secs
-            ach_bw = t.bytes / secs
-            row = {
-                "launches": t.launches,
-                "iterations": t.iterations,
-                "seconds": t.seconds,
-                "model_flops": t.flops,
-                "model_bytes": t.bytes,
-                "achieved_flops_per_s": ach_flops,
-                "achieved_bytes_per_s": ach_bw,
-                "frac_peak_flops": None,
-                "frac_peak_bw": None,
-                "dominant": None,
-            }
-            if hw is not None:
-                compute_s = t.flops / hw.peak_flops
-                memory_s = t.bytes / hw.hbm_bw
-                row.update(
-                    frac_peak_flops=ach_flops / hw.peak_flops,
-                    frac_peak_bw=ach_bw / hw.hbm_bw,
-                    dominant="compute" if compute_s >= memory_s
-                    else "memory")
-            out[name] = row
-        return out
-
-    def clear(self) -> None:
-        with self._lock:
-            self._tallies.clear()

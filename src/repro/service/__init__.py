@@ -13,8 +13,8 @@ the CLI front-end; ``benchmarks/bench_serve.py`` measures queries/sec.
 Observability (``repro.obs``) threads through the whole stack:
 ``DatalogService(tracer=True)`` records Chrome-exportable spans,
 ``metrics``/``svc.metrics`` is the unified counter/histogram registry
-(Prometheus + JSON exporters), ``probe=True`` surfaces per-iteration
-fixpoint Δs, and ``explain()["kernels"]`` reports roofline attribution.
+(Prometheus + JSON exporters), and ``explain()["relations"][pred]
+["fixpoint"]`` totals the fixpoint loops' own row and Δ counters.
 ``MetricsRegistry`` and ``Tracer`` are re-exported here for convenience.
 
 Durability (``durable.py``): ``DatalogService(durable_dir=...)`` write-ahead

@@ -200,7 +200,6 @@ class AsyncDatalogService:
             self._outstanding += 1
             self._waiting.append((fut, qlit, time.monotonic()))
             self._cv.notify_all()
-        svc.tracer.instant("submit", cat="admission", pred=qlit.pred)
         return fut
 
     def ask(self, query, timeout: float | None = None):
@@ -329,26 +328,39 @@ class AsyncDatalogService:
     def _flush(self, items: list) -> None:
         """Launch one flush under the fence's read side; hand the pending
         batch to the finalizer.  The read side stays held (by the pending)
-        until finalize completes — appends drain us, not the reverse."""
+        until finalize completes — appends drain us, not the reverse.
+
+        The ``flush`` span covers the launch and the block on the in-flight
+        bound; with the tracer on it carries the flush's number and each
+        query's wait from submit to leaving the queue (``queue_wait_ms``)."""
         futs = [f for f, _, _ in items]
         qlits = [q for _, q, _ in items]
         now = time.monotonic()
-        for _, _, t_submit in items:
-            self._h_qwait.observe(now - t_submit)
-        self._fence.acquire_read()
-        try:
-            with self.svc.lock:
-                pending = self.svc.launch_batch(qlits)
-        except BaseException as e:  # noqa: BLE001 — futures carry the error
-            self._fence.release_read()
-            self._fail(futs, e)
-            return
-        self.stats.flushes += 1
-        self.stats.flushed_queries += len(items)
-        self.stats.max_flush = max(self.stats.max_flush, len(items))
-        # double buffer: blocks while `inflight` batches await finalize —
-        # the device/host overlap depth, and backpressure toward the window
-        self._inflight.put((pending, futs))
+        tracer = self.svc.tracer
+        with tracer.span("flush", cat="admission") as span:
+            if tracer.enabled:
+                waits = [now - t_submit for _, _, t_submit in items]
+                span.annotate(queue_wait_ms=[w * 1e3 for w in waits])
+            else:  # no per-query list when nothing records it
+                waits = (now - t_submit for _, _, t_submit in items)
+            for w in waits:
+                self._h_qwait.observe(w)
+            self._fence.acquire_read()
+            try:
+                with self.svc.lock:
+                    pending = self.svc.launch_batch(qlits)
+            except BaseException as e:  # noqa: BLE001 — futures carry the error
+                self._fence.release_read()
+                self._fail(futs, e)
+                return
+            if tracer.enabled:
+                span.annotate(flush=pending.seq)
+            self.stats.flushes += 1
+            self.stats.flushed_queries += len(items)
+            self.stats.max_flush = max(self.stats.max_flush, len(items))
+            # double buffer: blocks while `inflight` batches await finalize —
+            # the device/host overlap depth, and backpressure toward the window
+            self._inflight.put((pending, futs))
 
     def _finalize_loop(self) -> None:
         while True:

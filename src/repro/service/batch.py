@@ -26,7 +26,6 @@ from ..core.semiring import Semiring
 from ..core.seminaive import (DenseResult, additive_max_iters,
                               check_additive_converged,
                               fixpoint_dense_cached)
-from ..obs.fixpoint_probe import fixpoint_csr_probed, fixpoint_dense_probed
 
 
 def pad_batch_size(b: int, pads: tuple[int, ...]) -> int:
@@ -60,7 +59,6 @@ def run_frontier_batch(
     mesh=None,
     max_iters: int | None = None,
     init: jax.Array | None = None,
-    probe: bool = False,
 ) -> DenseResult:
     """One batched fixpoint answering ``len(srcs)`` single-source queries.
 
@@ -69,11 +67,6 @@ def run_frontier_batch(
     so resume and cold batches share this dispatch (and its compilations).
     Returns a :class:`DenseResult` whose table's first ``len(srcs)`` rows are
     the closure rows of the requested sources (pad rows follow).
-
-    ``probe=True`` routes through the probed fixpoint twin
-    (``obs.fixpoint_probe``) and returns ``(DenseResult, FixpointProbe)``
-    with a bit-identical result; the mesh path has no probed twin and
-    returns ``(DenseResult, None)``.
     """
     b = len(srcs)
     bp = pad_batch_size(b, pads)
@@ -98,21 +91,18 @@ def run_frontier_batch(
         # accumulate fixpoint sums init·Aᵏ over path lengths, bounded by the
         # acyclicity iteration budget — hitting it raises
         # FixpointDivergenceError instead of serving a truncated count.
-        # The sharded and probed twins are vector-form only, so additive
-        # batches run the plain cached fixpoint (probe reports None).
+        # The sharded twin is vector-form only, so additive batches run the
+        # plain cached fixpoint.
         if max_iters is None:
             max_iters = additive_max_iters(matrix.shape[-1])
         res = fixpoint_dense_cached(sr, matrix, init, form="accumulate",
                                     matmul=matmul, max_iters=max_iters)
-        res = check_additive_converged(res, max_iters, "additive dense batch")
-        return (res, None) if probe else res
+        return check_additive_converged(res, max_iters,
+                                        "additive dense batch")
     if mesh is not None:
+        # the sharded loop counts no rows: active_rows/delta_facts stay -1
         closed, iters = _sharded(mesh, sr, matrix, init, matmul, max_iters)
-        res = DenseResult(closed, iters, jnp.int64(0))
-        return (res, None) if probe else res
-    if probe:
-        return fixpoint_dense_probed(sr, matrix, init, form="vector",
-                                     matmul=matmul, max_iters=max_iters)
+        return DenseResult(closed, iters, jnp.int64(0))
     return fixpoint_dense_cached(sr, matrix, init, form="vector",
                                  matmul=matmul, max_iters=max_iters)
 
@@ -131,7 +121,6 @@ def run_frontier_batch_csr(
     mesh=None,
     max_iters: int | None = None,
     init: jax.Array | None = None,
-    probe: bool = False,
 ) -> DenseResult:
     """CSR twin of :func:`run_frontier_batch`: the same (B, n) batched
     frontier fixpoint with per-row convergence masking, but each iteration is
@@ -169,16 +158,12 @@ def run_frontier_batch_csr(
             max_iters = additive_max_iters(csr.n_alloc)
         res = _sparse.fixpoint_csr_cached(csr, init, spmv=spmv,
                                           max_iters=max_iters)
-        res = check_additive_converged(res, max_iters, "additive CSR batch")
-        return (res, None) if probe else res
+        return check_additive_converged(res, max_iters, "additive CSR batch")
     if mesh is not None:
         from ..core.distributed import csr_frontier_decomposable
         closed, iters = csr_frontier_decomposable(mesh, csr, init, spmv=spmv,
                                                   max_iters=max_iters)
-        res = DenseResult(closed, iters, jnp.int64(0))
-        return (res, None) if probe else res
-    if probe:
-        return fixpoint_csr_probed(csr, init, spmv=spmv, max_iters=max_iters)
+        return DenseResult(closed, iters, jnp.int64(0))
     return _sparse.fixpoint_csr_cached(csr, init, spmv=spmv,
                                        max_iters=max_iters)
 

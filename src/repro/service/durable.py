@@ -306,6 +306,7 @@ def restore_state(svc, flat: dict) -> dict:
             ds.flips = int(d["flips"])
             ds.last_flip = d["last_flip"]
             ds.tuning = None
+            ds.fixpoint = dict.fromkeys(_DenseRelation.FIXPOINT_TOTALS, 0)
             if not ds.sr.idempotent:
                 edges = svc.db.get(low.edb)
                 ds._edges = set() if edges is None or not len(edges) else {
@@ -393,6 +394,7 @@ class DurabilityManager:
         self._ckpt = AsyncCheckpointer(self.snap_dir, n_shards=n_shards)
         self._replaying = False
         self._appends_since_snap = 0
+        self._last_step = 0  # the newest step handed to the writer
         self.counters = {"wal_records": 0, "wal_bytes": 0,
                          "snapshots": 0, "snapshot_errors": 0}
         #: recovery report, filled by :meth:`recover` (explain()/metrics)
@@ -430,12 +432,15 @@ class DurabilityManager:
         with self.tracer.span("snapshot", cat="durable", epoch=svc.epoch):
             flat = snapshot_state(svc, self.wal.records)
             steps = complete_steps(self.snap_dir)
-            step = (steps[0] + 1) if steps else 1
+            # the writer may not have published the step handed to it last:
+            # numbering from the disk alone would hand it the same step again
+            step = max(steps[0] if steps else 0, self._last_step) + 1
             try:
                 self._ckpt.save(step, flat)
             except CheckpointWriteError:
                 self.counters["snapshot_errors"] += 1
                 return None
+            self._last_step = step
             self.counters["snapshots"] += 1
             self._appends_since_snap = 0
             self._prune(keep_from=step)

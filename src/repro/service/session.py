@@ -51,8 +51,6 @@ from ..core.parser import parse_program
 from ..core.planner import PlanError, demanded_strata
 from ..core.semiring import MIN_PLUS, carrier_for, edge_arity
 from ..obs.metrics import NULL_METRICS, MetricsRegistry
-from ..obs.roofline_attr import (KernelAttribution, csr_launch_cost,
-                                 dense_launch_cost)
 from ..obs.trace import NULL_TRACER, Tracer
 from . import batch as _batch
 from . import incremental as _inc
@@ -92,10 +90,13 @@ class _PendingBatch:
     epoch: int  # service epoch at launch; finalize asserts it is unchanged
     qlits: list
     out: list  # answer slots; EDB selections fill at launch
+    #: the flush's number in the trace (``flush=`` on its spans); None when
+    #: the tracer is off
+    seq: int | None = None
     hits: list = dataclasses.field(default_factory=list)  # (slot, CacheEntry)
     #: [(pred, _DenseRelation, items, uniq_srcs, in_range, DenseResult|None,
-    #:   launch_meta)] — launch_meta carries the launch timestamp + batch
-    #: width for the roofline attribution recorded at device sync
+    #:   launch_meta)] — launch_meta carries the launch timestamp, which
+    #: ``datalog_device_seconds`` reads at device sync
     dense: list = dataclasses.field(default_factory=list)
     #: [(pred, items, uniq, (template, launched)|None, results|None)]
     tuples: list = dataclasses.field(default_factory=list)
@@ -128,6 +129,11 @@ class _DenseRelation:
     (re-running the heuristic — density drifts as graphs grow).
     """
 
+    #: running totals of the serving flushes' fixpoint counters, kept in
+    #: ``self.fixpoint`` (``explain()["relations"][pred]["fixpoint"]``)
+    FIXPOINT_TOTALS = ("launches", "rows", "pad_rows", "iterations",
+                       "row_iterations", "active_rows", "delta_facts")
+
     def __init__(self, svc: "DatalogService", low: FrontierLowering):
         self.low = low
         # the carrier-misrouting bug lived here: `BOOL if bool else MIN_PLUS`
@@ -141,6 +147,7 @@ class _DenseRelation:
         self.flips = 0  # representation changes across rebuilds (live
         self.last_flip: str | None = None  # density heuristic, ROADMAP 6c)
         self.tuning: dict | None = None  # autotuner report (tune= on)
+        self.fixpoint = dict.fromkeys(self.FIXPOINT_TOTALS, 0)
         self._rebuild(svc)
 
     @property
@@ -216,24 +223,31 @@ class _DenseRelation:
 
     def run_batch(self, svc: "DatalogService", srcs: list[int], init=None):
         """One batched frontier fixpoint over this relation's representation
-        (``init`` overrides the seed — append-resume).  In probe mode the
-        probed twin runs instead (bit-identical result) and its per-iteration
-        observations land on ``svc.last_probes``."""
+        (``init`` overrides the seed — append-resume)."""
         if self.is_csr:
-            res = _batch.run_frontier_batch_csr(
+            return _batch.run_frontier_batch_csr(
                 self.csr, srcs, svc.batch_pads,
                 spmv=svc._spmv(self.low.kind, self.csr),
-                mesh=svc.mesh, init=init, probe=svc.probe)
-        else:
-            res = _batch.run_frontier_batch(
-                self.sr, self.matrix, srcs, svc.batch_pads,
-                matmul=svc._matmul(self.sr), mesh=svc.mesh, init=init,
-                probe=svc.probe)
-        if svc.probe:
-            res, pr = res
-            if pr is not None:
-                svc._record_probe(pr)
-        return res
+                mesh=svc.mesh, init=init)
+        return _batch.run_frontier_batch(
+            self.sr, self.matrix, srcs, svc.batch_pads,
+            matmul=svc._matmul(self.sr), mesh=svc.mesh, init=init)
+
+    def tally(self, rows: int, pad: int, iterations: int, active_rows: int,
+              delta_facts: int) -> None:
+        """Add one serving flush's fixpoint counters to the totals; a
+        counter the loop did not count (-1, the sharded path) is left
+        out."""
+        t = self.fixpoint
+        t["launches"] += 1
+        t["rows"] += rows
+        t["pad_rows"] += pad
+        t["iterations"] += iterations
+        t["row_iterations"] += pad * iterations
+        if active_rows >= 0:
+            t["active_rows"] += active_rows
+        if delta_facts >= 0:
+            t["delta_facts"] += delta_facts
 
     def append(self, svc: "DatalogService", rows: np.ndarray) -> bool:
         """Fold appended arcs in; returns True when the domain outgrew the
@@ -562,13 +576,13 @@ class DatalogService:
     ``tracer``        span tracer (``obs.trace``): ``None``/``False`` is the
                       no-op ``NULL_TRACER``, ``True`` creates a recording
                       ``Tracer``, or pass one (``svc.tracer.export_chrome``
-                      writes the timeline).
-    ``probe``         route dense/CSR frontier fixpoints through the probed
-                      twins (``obs.fixpoint_probe``): results stay
-                      bit-identical, per-iteration frontier/Δ observations
-                      accumulate on ``last_probes`` and ``explain()``.
-                      Costs one host sync per fixpoint iteration — keep off
-                      the steady-state path.
+                      writes the timeline).  Each flush is numbered: its
+                      ``launch_batch``, ``finalize_batch`` and
+                      ``device_sync`` spans carry ``flush=<seq>``,
+                      ``launch_batch`` also ``ahead`` (flushes launched and
+                      not yet synced at its start), and ``device_sync`` the
+                      fixpoint's counters (``rows``, ``pad``,
+                      ``iterations``, ``active_rows``, ``delta_facts``).
     ``durable_dir``   crash-safe persistence root (``service/durable.py``):
                       every append WALs before mutating, :meth:`snapshot`
                       persists the hot state through the background
@@ -597,7 +611,7 @@ class DatalogService:
                  sparse_threshold: float | None = None,
                  csr_rebuild_frac: float = 0.25, snapshot_lru: int = 1,
                  bucket_floors: dict[str, int] | None = None,
-                 tune=None, metrics=None, tracer=None, probe: bool = False,
+                 tune=None, metrics=None, tracer=None,
                  durable_dir=None, snapshot_every: int = 0,
                  keep_snapshots: int = 3, durable_fsync: bool = True):
         if isinstance(program, str):
@@ -640,22 +654,21 @@ class DatalogService:
         #: use; the admission front-end (``admission.py``) launches flushes,
         #: finalizes them and probes the cache from different threads.
         self.lock = threading.RLock()
-        # -- observability (obs/): tracer, metrics, probes, roofline ---------
-        self.probe = bool(probe)
-        self.last_probes: list = []  # recent FixpointProbe records (capped)
+        # -- observability (obs/): tracer, metrics -----------------------------
         if tracer is None or tracer is False:
             self.tracer = NULL_TRACER
         elif tracer is True:
             self.tracer = Tracer()
         else:
             self.tracer = tracer
+        self._flush_seq = 0  # flushes numbered (tracer on only)
+        self._unsynced = 0  # of those, launched and not yet synced
         if metrics is False:
             self.metrics = NULL_METRICS
         elif metrics is None or metrics is True:
             self.metrics = MetricsRegistry()
         else:
             self.metrics = metrics
-        self.kernels = KernelAttribution()
         self._h_device = self.metrics.histogram(
             "datalog_device_seconds",
             "launch to device-sync wall time per batched fixpoint")
@@ -709,12 +722,14 @@ class DatalogService:
         selection / dense-coalescible / tuple shape) and dispatch every
         device fixpoint.  Returns the in-flight state for
         :meth:`finalize_batch`; must run under :attr:`lock`."""
+        seq, ahead = self._number_flush()
         with self.lock, self.tracer.span("launch_batch", cat="service",
-                                         batch=len(queries)):
+                                         batch=len(queries), flush=seq,
+                                         ahead=ahead):
             self._h_batch.observe(len(queries))
             qlits = [self._as_literal(s) for s in queries]
             pending = _PendingBatch(epoch=self.epoch, qlits=qlits,
-                                    out=[None] * len(qlits))
+                                    out=[None] * len(qlits), seq=seq)
             dense: dict[str, list[tuple[int, int, Literal]]] = {}
             singles: list[tuple[int, Literal]] = []
             for i, q in enumerate(qlits):
@@ -743,7 +758,20 @@ class DatalogService:
             for (pred, adn), items in shapes.items():
                 pending.tuples.append(
                     self._launch_tuple_group(pred, adn, items))
+            if seq is not None:
+                self._unsynced += 1  # until finalize_batch has synced it
             return pending
+
+    def _number_flush(self) -> tuple:
+        """``(seq, ahead)`` for a flush's spans: its number, and the flushes
+        launched and not yet synced as it starts (the device queue it
+        joins).  ``(None, None)`` with the tracer off: nothing is counted
+        or locked for the trace."""
+        if not self.tracer.enabled:
+            return None, None
+        with self.lock:
+            self._flush_seq += 1
+            return self._flush_seq, self._unsynced
 
     def finalize_batch(self, pending: "_PendingBatch") -> list:
         """Phase 2 of :meth:`ask_batch`: block on the launched device
@@ -754,20 +782,15 @@ class DatalogService:
         (``incremental.EpochFence`` enforces this for the async front-end).
         """
         with self.tracer.span("finalize_batch", cat="service",
-                              batch=len(pending.qlits)):
+                              batch=len(pending.qlits), flush=pending.seq):
             t_fin = time.monotonic()
-            dense_done = []
-            for pred, ds, items, uniq, in_range, res, meta in pending.dense:
-                # ONE host transfer per group (the device sync of the whole
-                # batched fixpoint); per-row jax indexing would compile a tiny
-                # gather per (shape, row) pair on the serving hot path
-                with self.tracer.span("device_sync", cat="device", pred=pred):
-                    table = np.asarray(res.table) if in_range else None
-                if in_range:
-                    self._attribute_launch(ds, res, meta)
-                formatted = {s: (self._format(ds, s, table[j]), table[j])
-                             for j, s in enumerate(in_range)}
-                dense_done.append((pred, ds, items, uniq, formatted))
+            try:
+                dense_done = [self._sync_dense(pending.seq, *group)
+                              for group in pending.dense]
+            finally:
+                if pending.seq is not None:
+                    with self.lock:
+                        self._unsynced -= 1
             tuple_done = []
             for pred, items, uniq, launched, results in pending.tuples:
                 if results is None:  # batched: split the captured model now
@@ -784,7 +807,9 @@ class DatalogService:
                 out = pending.out
                 for i, ent in pending.hits:
                     out[i] = self._entry_result(ent)
-                for pred, ds, items, uniq, formatted in dense_done:
+                for pred, ds, items, uniq, formatted, counted in dense_done:
+                    if counted is not None:
+                        ds.tally(*counted)
                     final: dict[int, object] = {}
                     for s, (fmt, raw) in formatted.items():
                         self._cache_dense(pred, s, fmt, raw)
@@ -963,18 +988,20 @@ class DatalogService:
         ``cache``      ``{entries, hits, misses, evictions}``
         ``templates``  memoized ``pred/adornment`` shapes (sorted list)
         ``relations``  per-predicate carrier reports: ``{n, n_alloc,
-                       semiring, repr}`` plus ``flips``/``last_flip`` after
-                       representation flips and ``nnz``/``density``/
-                       ``e_alloc``/``padding`` (the sliced-ELL per-slice
-                       allocation report) for CSR
-        ``kernels``    roofline attribution per kernel
-                       (:meth:`~repro.obs.roofline_attr.KernelAttribution.report`),
-                       plus a ``tuning`` entry per tuned predicate (chosen
+                       semiring, repr, fixpoint}`` plus ``flips``/
+                       ``last_flip`` after representation flips and
+                       ``nnz``/``density``/``e_alloc``/``padding`` (the
+                       sliced-ELL per-slice allocation report) for CSR.
+                       ``fixpoint`` holds running totals over the serving
+                       flushes' fixpoints: ``launches``, ``rows`` (live),
+                       ``pad_rows``, ``iterations``, ``row_iterations``
+                       (pad × iterations), ``active_rows`` (Σ unconverged
+                       rows entering each step) and ``delta_facts`` (Σ
+                       entries changed); append resumes are not counted
+        ``kernels``    a ``tuning`` entry per tuned predicate (chosen
                        :class:`~repro.kernels.autotune.KernelConfig`,
                        measured gain, achieved-vs-peak fractions) when
-                       ``tune=`` is on
-        ``probes``     recent per-iteration fixpoint observations (probe
-                       mode only; :class:`~repro.obs.FixpointProbe` dicts)
+                       ``tune=`` is on; empty otherwise
 
         The async front-end nests its report under ``admission``
         (``{queue, window, counters}`` — see
@@ -997,6 +1024,7 @@ class DatalogService:
             "relations": {p: {"n": ds.n, "n_alloc": ds.n_alloc,
                               "semiring": ds.sr.name,
                               "repr": "csr" if ds.is_csr else "dense",
+                              "fixpoint": dict(ds.fixpoint),
                               **({"flips": ds.flips,
                                   "last_flip": ds.last_flip}
                                  if ds.flips else {}),
@@ -1007,39 +1035,14 @@ class DatalogService:
                                   "padding": ds.csr.padding_waste()}
                                  if ds.is_csr else {})}
                           for p, ds in self._dense.items()},
-            "kernels": self.kernels.report(),
+            "kernels": {},
         }
         tuning = {p: ds.tuning for p, ds in self._dense.items() if ds.tuning}
         if tuning:
             rep["kernels"]["tuning"] = tuning
-        if self.probe:
-            rep["probes"] = [p.as_dict() for p in self.last_probes]
         if self._durable is not None:
             rep["durability"] = self._durable.report()
         return rep
-
-    def _record_probe(self, pr) -> None:
-        self.last_probes.append(pr)
-        del self.last_probes[:-64]  # bounded: recent batches only
-
-    def _attribute_launch(self, ds: _DenseRelation, res, meta: dict) -> None:
-        """Roofline attribution at the device sync point: measured
-        launch→sync wall time + the analytic flop/byte model for the padded
-        batch that actually ran (``obs.roofline_attr``)."""
-        secs = time.monotonic() - meta["t_launch"]
-        self._h_device.observe(secs)
-        iters = int(res.iterations)
-        bp = _batch.pad_batch_size(max(meta["b"], 1), self.batch_pads)
-        if ds.is_csr:
-            e_alloc = ds.csr.e_alloc  # sliced spine + tail allocation
-            cost = csr_launch_cost(bp, ds.n_alloc, e_alloc,
-                                   ds.csr.edge_val.dtype.itemsize, iters)
-            kernel = f"csr_spmv:{ds.low.kind}"
-        else:
-            cost = dense_launch_cost(bp, ds.n_alloc,
-                                     ds.matrix.dtype.itemsize, iters)
-            kernel = f"frontier_matmul:{ds.low.kind}"
-        self.kernels.record(kernel, seconds=secs, iterations=iters, **cost)
 
     def _absorb_stats(self, m) -> None:
         """Export-time absorption (``MetricsRegistry.register_collector``):
@@ -1220,6 +1223,33 @@ class DatalogService:
             self.stats.csr_fixpoints += 1 if ds.is_csr else 0
             self.stats.batched_queries += len(in_range)
         return (pred, ds, items, uniq, in_range, res, meta)
+
+    def _sync_dense(self, seq, pred, ds, items, uniq, in_range, res, meta):
+        """Wait for one dense group's fixpoint and format its rows.  ONE
+        host transfer per group, the table and the loop's counters together
+        (the device sync of the whole batched fixpoint); per-row jax indexing
+        would compile a tiny gather per (shape, row) pair on the serving hot
+        path.  Returns the group with its formatted rows and its counters
+        ``(rows, pad, iterations, active_rows, delta_facts)``, or None where
+        nothing was launched."""
+        table = counted = None
+        with self.tracer.span("device_sync", cat="device", pred=pred,
+                              flush=seq) as sync:
+            if in_range:
+                table, iters, active, delta = jax.device_get(
+                    (res.table, res.iterations, res.active_rows,
+                     res.delta_facts))
+                counted = (len(in_range), table.shape[0], int(iters),
+                           int(active), int(delta))
+                if self.tracer.enabled:
+                    sync.annotate(**dict(zip(
+                        ("rows", "pad", "iterations", "active_rows",
+                         "delta_facts"), counted)))
+        if in_range:
+            self._h_device.observe(time.monotonic() - meta["t_launch"])
+        formatted = {s: (self._format(ds, s, table[j]), table[j])
+                     for j, s in enumerate(in_range)}
+        return pred, ds, items, uniq, formatted, counted
 
     def _cache_dense(self, pred: str, src: int, formatted, raw):
         low = self._lowering(pred)

@@ -20,13 +20,14 @@ fixpoint over Python sets — on TEN evaluation paths:
                                  flush composition is timing-dependent, so
                                  answers are compared as sets — the invariant
                                  is that coalescing NEVER changes an answer)
-  9. observed serving            (``probe=True`` + ``tracer=True``: the
-                                 probed fixpoint twins and span recording
-                                 must be bit-identical to the plain dense
-                                 service, and re-serving a warm batch must
-                                 not retrace any fixpoint; additive batches
-                                 run unprobed — the gate must not perturb
-                                 answers)
+  9. observed serving            (``tracer=True``: span recording must be
+                                 bit-identical to the plain dense service,
+                                 the fixpoint counters in ``explain()``
+                                 must match the flushes' ``device_sync``
+                                 spans and, on the bool carrier, balance
+                                 seed + ΣΔ == final against the reference
+                                 model, and re-serving a warm batch must
+                                 neither launch nor retrace a fixpoint)
  10. tuned-kernel serving        (a pinned ``KernelConfig(use_kernel=True)``
                                  forces sliced-ELL + the Pallas tile-skip
                                  kernels on every CSR relation; answers must
@@ -163,6 +164,37 @@ def check(path: str, case, q, got, want):
 
 CAPS = dict(default_cap=4096)
 
+COUNTERS = {"launches": None, "rows": "rows", "pad_rows": "pad",
+            "iterations": "iterations", "active_rows": "active_rows",
+            "delta_facts": "delta_facts"}
+
+
+def check_fixpoint_accounting(case, svc, ref, queries):
+    """``explain()``'s fixpoint totals equal the sums over the flushes'
+    ``device_sync`` spans.  On an idempotent carrier the seed rows (each
+    launched source's out-arcs) plus the Δ entries cover the reference
+    model's facts for those sources: exactly on the bool carrier, where an
+    entry changes once; at least on min/max-plus, where Δ also counts
+    improvements."""
+    syncs = [e["args"] for e in svc.tracer.spans("device_sync")]
+    for pred, rel in svc.explain()["relations"].items():
+        fx, mine = rel["fixpoint"], [a for a in syncs if a["pred"] == pred]
+        for key, arg in COUNTERS.items():
+            want = len(mine) if arg is None else sum(a[arg] for a in mine)
+            assert fx[key] == want, (case, pred, key, fx)
+        low = svc._lowering(pred)
+        if low.kind == "plustimes" or not fx["launches"]:
+            continue
+        srcs = {svc._dense_source(q) for q in queries if q.pred == pred}
+        seed = len({(int(r[0]), int(r[1])) for r in svc.db[low.edb]
+                    if int(r[0]) in srcs})
+        final = len({t[:2] for t in ref.get(pred, ()) if t[0] in srcs})
+        if low.kind == "bool":
+            assert seed + fx["delta_facts"] == final, (case, pred, fx)
+        else:
+            assert seed + fx["delta_facts"] >= final > 0 or final == 0, \
+                (case, pred, fx)
+
 
 @pytest.mark.parametrize("case", range(DIFF_CASES))
 def test_differential(case):
@@ -267,11 +299,12 @@ def test_differential(case):
               want[i])
     front.close()
 
-    # 9. observed serving: probes + tracing on must not perturb answers —
-    # bit-identical to the plain dense service — and warm re-serving must
-    # be retrace-free (probed twins keep their own jit cache)
+    # 9. observed serving: tracing on must not perturb answers — bit-
+    # identical to the plain dense service — the loops' counters must
+    # account for every Δ, and warm re-serving must be launch- and
+    # retrace-free
     from repro.core.engine import fixpoint_trace_count
-    svc_obs = DatalogService(text, db=db, probe=True, tracer=True, **CAPS)
+    svc_obs = DatalogService(text, db=db, tracer=True, **CAPS)
     for i, got in enumerate(svc_obs.ask_batch(queries)):
         check("service-observed", case, queries[i], got, want[i])
         d = dense_res[i]
@@ -279,13 +312,15 @@ def test_differential(case):
                         got if isinstance(got, tuple) else (got,)):
             assert np.array_equal(a, b), \
                 f"case={case} query={queries[i]!r}: observed not bit-identical"
-    for p in svc_obs.last_probes:  # Δ accounting holds on every probed run
-        assert p.seed_facts + p.total_delta == p.final_facts, (case, p)
+    check_fixpoint_accounting(case, svc_obs, ref, queries)
     tc0 = fixpoint_trace_count()
+    totals = svc_obs.explain()["relations"]
     for i, got in enumerate(svc_obs.ask_batch(queries)):  # warm batch
         check("service-observed-warm", case, queries[i], got, want[i])
     assert fixpoint_trace_count() == tc0, \
         f"case={case}: warm observed batch retraced a fixpoint"
+    assert svc_obs.explain()["relations"] == totals, \
+        f"case={case}: warm observed batch launched a fixpoint"
 
     # 6. append-resume: serve a prefix EDB, append the tail, re-serve
     rel = SHAPES[shape][2][0]

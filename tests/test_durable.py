@@ -327,3 +327,32 @@ def test_async_checkpointer_error_raises_once_then_recovers(tmp_path):
     ckpt.save(2, {"x": np.zeros(3)})
     ckpt.wait()  # does NOT re-raise the old error
     ckpt.close()
+
+
+def test_snapshot_steps_distinct_while_writer_busy(tmp_path, monkeypatch):
+    """Regression: a snapshot taken while the writer still held the last
+    one was numbered from the published steps alone, so it reused that
+    step and overwrote it — one generation where there should be two."""
+    import threading
+
+    from repro.checkpoint import store
+
+    gate = threading.Event()
+    real = store.save_checkpoint
+
+    def held(*a, **kw):
+        assert gate.wait(60)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(store, "save_checkpoint", held)
+    svc = DatalogService(TC, {"e": _edges(seed=9)}, durable_dir=tmp_path,
+                         **CAPS)
+    svc.ask("tc", (1, None))
+    first = svc.snapshot()
+    svc.append("e", np.array([[1, 45]], np.int64))
+    second = svc.snapshot()
+    gate.set()
+    svc._durable.wait()
+    assert (first, second) == (1, 2)
+    assert complete_steps(tmp_path / "snapshots") == [2, 1]
+    svc.close()

@@ -1,4 +1,5 @@
-"""Observability layer tests: tracing, metrics, probes, cache thread-safety.
+"""Observability layer tests: tracing, metrics, fixpoint counters, cache
+thread-safety.
 
 Covers the ``repro.obs`` package and its integration into the serving
 stack:
@@ -9,8 +10,9 @@ stack:
   tid are either disjoint or properly nested, never partially overlapping);
 * ``MetricsRegistry`` under concurrent mutation: exact totals, exporter
   formats, collector absorption;
-* probed fixpoint twins are bit-identical to the unprobed fixpoints and
-  their per-iteration Δ-fact counts sum to the oracle's derived-fact total;
+* the fixpoint loops' own counters: Δ entries sum to the oracle's
+  derived-fact total (seed + Δ == final), active rows match known depths,
+  and the service reports their running totals through ``explain()``;
 * the ``LRUCache.hits``/``CacheEntry.hits`` thread-safety regression: the
   bumps used to be bare ``+=`` racing between submitter threads and the
   dispatcher — exact counts under a thread hammer prove the lock.
@@ -26,7 +28,6 @@ from _reference import ref_distances, ref_reachable
 from repro.core.engine import Engine
 from repro.obs import (
     DEFAULT_BUCKETS,
-    KernelAttribution,
     MetricsRegistry,
     NULL_METRICS,
     NULL_TRACER,
@@ -291,58 +292,117 @@ def test_lru_cache_hit_counts_exact_under_threads():
     assert cache.misses == threads * per
 
 
-# -- probed fixpoint twins --------------------------------------------------
+# -- fixpoint counters -------------------------------------------------------
+
+def facts(table) -> int:
+    """Entries of a closure table that are not the ⊕-zero."""
+    t = np.asarray(table)
+    return int(np.count_nonzero(t if t.dtype == bool else np.isfinite(t)))
+
+
+def fixpoint_on(sparse: bool, edges: np.ndarray, n: int, srcs, kind="bool"):
+    """The batched frontier fixpoint of the serving path, dense or CSR."""
+    import jax.numpy as jnp
+
+    from repro.core import seminaive as sn
+    from repro.core import sparse as sp
+    if sparse:
+        csr = sp.build_csr(edges, n, kind)
+        run = sp.reachable_batch_csr if kind == "bool" \
+            else sp.distances_batch_csr
+        return sp.rows_from_sources(csr, srcs), run(csr, srcs)
+    if kind == "bool":
+        adj = np.zeros((n, n), bool)
+        adj[edges[:, 0], edges[:, 1]] = True
+        return adj[srcs], sn.reachable_batch_dense(jnp.asarray(adj), srcs)
+    w = np.full((n, n), np.inf, np.float32)
+    np.minimum.at(w, (edges[:, 0], edges[:, 1]), edges[:, 2].astype(np.float32))
+    return w[srcs], sn.distances_batch_dense(jnp.asarray(w), srcs)
+
 
 @pytest.mark.parametrize("sparse", [False, True],
                          ids=["dense", "csr"])
-def test_probed_bit_identical_and_delta_oracle(sparse):
+def test_delta_facts_oracle(sparse):
     edges = gnp(48, 0.08, seed=3)
-    eng = Engine(TC, db={"arc": edges}, default_cap=4096)
-    for src in (0, 5, 17):
-        plain = eng.ask_dense("tc", (src, None), sparse=sparse)
-        got, pr = eng.ask_dense("tc", (src, None), sparse=sparse, probe=True)
-        assert np.array_equal(np.asarray(plain), np.asarray(got)), \
-            "probed twin must be bit-identical"
-        want = ref_reachable(edges, src)
-        assert pr.final_facts == len(want)
-        # per-iteration Δ-fact counts sum to the oracle's derived total
-        assert pr.seed_facts + pr.total_delta == len(want)
-        assert pr.repr == ("csr" if sparse else "dense")
-        assert pr.iterations == len(pr.delta_facts) == len(pr.frontier_rows)
-        d = pr.as_dict()
-        assert d["repr"] == pr.repr and d["final_facts"] == len(want)
+    srcs = [0, 5, 17]
+    seed, res = fixpoint_on(sparse, edges, 48, srcs)
+    table = np.asarray(res.table)
+    want = [ref_reachable(edges, s) for s in srcs]
+    for j, s in enumerate(srcs):
+        assert set(np.nonzero(table[j])[0].tolist()) == set(want[j])
+    # the loop's Δ entries sum to the oracle's derived-fact total
+    assert facts(seed) + int(res.delta_facts) == facts(table) \
+        == sum(len(w) for w in want)
+    # every row is active entering the first step, and some row entering
+    # each later one; no more than all rows in any
+    assert int(res.iterations) <= int(res.active_rows) \
+        <= len(srcs) * int(res.iterations)
 
 
-def test_probed_minplus_matches_oracle_distances():
+@pytest.mark.parametrize("sparse", [False, True],
+                         ids=["dense", "csr"])
+def test_minplus_delta_facts_matches_oracle_distances(sparse):
     rng = np.random.default_rng(7)
     w = np.asarray([[a, b, int(rng.integers(1, 9))]
                     for a, b in gnp(24, 0.12, seed=11)], np.int64)
-    eng = Engine(SP, db={"w": w}, default_cap=4096)
-    plain = eng.ask_dense("sp", (0, None))
-    got, pr = eng.ask_dense("sp", (0, None), probe=True)
-    for a, b in zip(plain, got):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-    rows, vals = got
+    seed, res = fixpoint_on(sparse, w, 24, [0], kind="minplus")
+    row = np.asarray(res.table)[0]
     want = ref_distances(w, 0)
-    assert {int(r[1]): int(v) for r, v in zip(rows, vals)} == want
-    # min-plus Δ counts improvements; the final fact count still matches
-    assert pr.final_facts == len(want)
+    got = {int(v): int(row[v]) for v in np.nonzero(np.isfinite(row))[0]}
+    assert got == want
+    # min-plus Δ counts improvements too: at least the facts found, and the
+    # final fact count still matches the oracle
+    assert facts(res.table) == len(want)
+    assert int(res.delta_facts) >= facts(res.table) - facts(seed)
+    # one row: active entering every step, the last one included
+    assert int(res.active_rows) == int(res.iterations)
 
 
-def test_service_probe_mode_answers_and_explain():
+@pytest.mark.parametrize("sparse", [False, True],
+                         ids=["dense", "csr"])
+def test_active_rows_on_a_chain_at_a_padded_width(sparse):
+    """On the chain 0 -> 1 -> ... -> n-1, source s's row is active entering
+    the n-1-s steps that add a vertex and the one that finds none; a pad
+    row only entering the first.  The loop runs until the deepest row
+    settles."""
+    from repro.core.semiring import BOOL
+    from repro.service.batch import run_frontier_batch, run_frontier_batch_csr
+
+    n = 24
+    edges = np.asarray([[i, i + 1] for i in range(n - 1)], np.int64)
+    srcs = [0, 10, 22]  # depths 22, 12 and 0; pad rows: 5 at width 8
+    if sparse:
+        from repro.core.sparse import build_csr
+        res = run_frontier_batch_csr(build_csr(edges, n), srcs, (8,))
+    else:
+        import jax.numpy as jnp
+        adj = np.zeros((n, n), bool)
+        adj[edges[:, 0], edges[:, 1]] = True
+        res = run_frontier_batch(BOOL, jnp.asarray(adj), srcs, (8,))
+    assert np.asarray(res.table).shape == (8, n)
+    depth = [n - 2 - s for s in srcs]  # vertices each row adds
+    assert int(res.iterations) == max(depth) + 1
+    assert int(res.active_rows) == sum(d + 1 for d in depth) + (8 - len(srcs))
+    assert int(res.delta_facts) == sum(depth)
+
+
+def test_service_fixpoint_totals_in_explain():
     edges = gnp(40, 0.08, seed=5)
-    queries = [f"tc({s}, X)" for s in (0, 3, 9, 12)]
-    base = DatalogService(TC, db={"arc": edges}, default_cap=4096)
-    svc = DatalogService(TC, db={"arc": edges}, default_cap=4096, probe=True)
-    for a, b in zip(base.ask_batch(queries), svc.ask_batch(queries)):
-        assert np.array_equal(np.asarray(a), np.asarray(b)), \
-            "probe mode must not change answers"
-    assert svc.last_probes, "probe mode should record fixpoint probes"
-    rep = svc.explain()
-    assert rep["probes"] and rep["probes"][-1]["iterations"] >= 1
-    # batched probe Δ accounting: seed + ΣΔ == final, per probe record
-    for p in svc.last_probes:
-        assert p.seed_facts + p.total_delta == p.final_facts
+    srcs = (0, 3, 9, 12)
+    svc = DatalogService(TC, db={"arc": edges}, default_cap=4096,
+                         batch_pads=(8,))
+    answers = svc.ask_batch([f"tc({s}, X)" for s in srcs])
+    for s, a in zip(srcs, answers):
+        assert {int(r[1]) for r in np.asarray(a)} == set(ref_reachable(edges, s))
+    svc.ask_batch([f"tc({s}, X)" for s in srcs])  # cache hits: no launch
+    fx = svc.explain()["relations"]["tc"]["fixpoint"]
+    assert fx["launches"] == 1 and fx["rows"] == 4 and fx["pad_rows"] == 8
+    assert fx["iterations"] >= 1
+    assert fx["row_iterations"] == 8 * fx["iterations"]
+    assert fx["iterations"] <= fx["active_rows"] <= fx["row_iterations"]
+    # batched Δ accounting: seed rows (the sources' out-arcs) + ΣΔ == final
+    seed = sum(int((edges[:, 0] == s).sum()) for s in srcs)
+    assert seed + fx["delta_facts"] == sum(len(a) for a in answers)
 
 
 # -- service tracing integration -------------------------------------------
@@ -403,9 +463,14 @@ def test_admission_racing_submitters_disjoint_well_formed_traces():
     # every per-thread lane is independently well-formed: the racing
     # submitters, the dispatcher and the finalizer never corrupt each other
     assert well_formed(xs)
-    submits = [e for e in evs if e["name"] == "submit"]
-    assert len(submits) == len(queries)
-    assert len({e["tid"] for e in submits}) == 2  # two racing submitter tids
+    # each query's queue wait is on exactly one flush span, and each flush
+    # span names the launch it covers
+    flushes = [e for e in xs if e["name"] == "flush"]
+    waits = [w for e in flushes for w in e["args"]["queue_wait_ms"]]
+    assert len(waits) == len(queries) and all(w >= 0 for w in waits)
+    launched = {e["args"]["flush"] for e in xs if e["name"] == "launch_batch"}
+    assert {e["args"]["flush"] for e in flushes} == launched
+    assert not [e for e in evs if e["ph"] == "i"]  # no per-query instants
     coalesce = [e for e in xs if e["name"] == "coalesce"]
     assert coalesce and all("batch" in e.get("args", {}) for e in coalesce)
     assert sum(e["args"]["batch"] for e in coalesce) == len(queries)
@@ -455,55 +520,37 @@ def test_admission_metrics_and_explain_canonical_schema():
     assert "datalog_queue_wait_seconds_count 4" in text
 
 
-# -- roofline attribution ---------------------------------------------------
+# -- launch cost model ------------------------------------------------------
 
-def test_kernel_attribution_report():
+def test_launch_cost_formulas():
+    from repro.obs.roofline_attr import achieved_fractions
     from repro.roofline.report import V5E
-    ka = KernelAttribution(hw=V5E)
     cost = dense_launch_cost(B=8, n=1024, itemsize=4, iters=10)
     assert cost["flops"] == 2 * 8 * 1024 * 1024 * 10
-    ka.record("frontier_matmul:bool", seconds=0.01, iterations=10, **cost)
-    ka.record("frontier_matmul:bool", seconds=0.01, iterations=10, **cost)
+    assert cost["bytes"] == 4 * (1024 * 1024 + 2 * 8 * 1024) * 10
     ccost = csr_launch_cost(B=8, n_alloc=1024, e_alloc=4096, itemsize=4,
                             iters=5)
     assert ccost["flops"] == 2 * 8 * 4096 * 5
-    ka.record("csr_spmv:bool", seconds=0.002, iterations=5, **ccost)
-    rep = ka.report()
-    mm = rep["frontier_matmul:bool"]
-    assert mm["launches"] == 2 and mm["iterations"] == 20
-    assert mm["achieved_flops_per_s"] == pytest.approx(
-        2 * cost["flops"] / 0.02)
-    assert mm["frac_peak_flops"] == pytest.approx(
-        mm["achieved_flops_per_s"] / V5E.peak_flops)
-    assert 0 < mm["frac_peak_flops"] and mm["dominant"] in ("compute",
-                                                            "memory")
-    assert rep["csr_spmv:bool"]["launches"] == 1
-    ka.clear()
-    assert ka.report() == {}
-    # peaks come from the running device's kind: the CPU has none, so
-    # nothing is reported as a fraction of a chip's peak
-    cpu = KernelAttribution()
-    cpu.record("csr_spmv:bool", seconds=0.002, iterations=5, **ccost)
-    row = cpu.report()["csr_spmv:bool"]
-    assert row["achieved_flops_per_s"] > 0
-    assert row["frac_peak_flops"] is None and row["frac_peak_bw"] is None
-    assert row["dominant"] is None
+    assert ccost["bytes"] == (4096 * 12 + 4 * 2 * 8 * 1024
+                              + 4 * 8 * 4096) * 5
+    frac = achieved_fractions(ccost, 0.002, V5E)
+    assert frac["frac_peak_flops"] == pytest.approx(
+        ccost["flops"] / 0.002 / V5E.peak_flops)
+    assert frac["frac_peak_bw"] == pytest.approx(
+        ccost["bytes"] / 0.002 / V5E.hbm_bw)
+    # without a chip's peaks nothing is reported as a fraction of one
+    assert achieved_fractions(ccost, 0.002, None) == {
+        "frac_peak_flops": None, "frac_peak_bw": None}
 
 
-def test_service_kernel_attribution_in_explain():
+def test_service_explain_kernels_has_no_launch_records():
     svc = DatalogService(TC, db={"arc": gnp(64, 0.06, seed=2)},
                          default_cap=4096)
     svc.ask_batch(["tc(0, X)", "tc(1, X)", "tc(2, X)"])
-    kernels = svc.explain()["kernels"]
-    assert kernels, "frontier launches should be attributed"
-    for name, k in kernels.items():
-        if name == "tuning":  # autotuner report, not a launch record
-            continue
-        assert name.split(":")[0] in ("frontier_matmul", "csr_spmv")
-        assert k["launches"] >= 1 and k["seconds"] > 0
-        # the tests run on the CPU, whose kind has no peaks: not measured
-        assert k["dominant"] is None
-        assert k["frac_peak_flops"] is None and k["frac_peak_bw"] is None
+    rep = svc.explain()
+    # only the autotuner's entries live there, and this service tunes none
+    assert rep["kernels"] == {}
+    assert rep["relations"]["tc"]["fixpoint"]["launches"] == 1
 
 
 # -- trace-counter race + generated-fact counter dtype regressions ----------
@@ -533,16 +580,16 @@ def test_trace_count_thread_hammer_exact():
 
 
 def test_generated_counter_uses_realized_dtype():
-    """Regression: the probe/fixpoint fact counters asked for ``jnp.int64``,
-    which silently realizes as int32 without ``jax_enable_x64`` — so the
-    saturation guard was checking a bound the counter couldn't represent.
+    """Regression: the fixpoint fact counters asked for ``jnp.int64``,
+    which silently realizes as int32 without ``jax_enable_x64`` — so a
+    saturation check would test a bound the counter couldn't represent.
     The counters must carry ``GEN_DTYPE`` (the dtype that actually exists)
-    end to end, and Δ accounting must balance exactly: seed + ΣΔ == final."""
+    end to end through the cached fixpoint, and Δ accounting must balance
+    exactly: seed + ΣΔ == final."""
     import jax.numpy as jnp
 
-    from repro.core.seminaive import GEN_DTYPE, GEN_MAX
-    from repro.obs.fixpoint_probe import fixpoint_dense_probed
     from repro.core.semiring import BOOL
+    from repro.core.seminaive import GEN_DTYPE, GEN_MAX, fixpoint_dense_cached
 
     assert GEN_MAX == jnp.iinfo(GEN_DTYPE).max  # guard checks the real bound
     edges = gnp(32, 0.1, seed=2)
@@ -550,35 +597,176 @@ def test_generated_counter_uses_realized_dtype():
     adj[edges[:, 0], edges[:, 1]] = True
     init = np.zeros((3, 32), bool)
     init[[0, 1, 2], [0, 5, 9]] = True
-    res, pr = fixpoint_dense_probed(BOOL, jnp.asarray(adj), jnp.asarray(init))
+    res = fixpoint_dense_cached(BOOL, jnp.asarray(adj), jnp.asarray(init),
+                                form="vector")
     assert res.generated.dtype == GEN_DTYPE
-    assert pr.seed_facts + pr.total_delta == pr.final_facts
-    assert 0 <= pr.total_delta < int(GEN_MAX)
+    assert res.delta_facts.dtype == GEN_DTYPE
+    assert res.active_rows.dtype == jnp.int32
+    assert facts(init) + int(res.delta_facts) == facts(res.table)
+    assert 0 <= int(res.delta_facts) < int(GEN_MAX)
 
 
-def test_probed_twins_reject_additive_carriers():
-    """The probed twins replicate the masked vector form; the additive
-    (+,×) carrier runs the accumulate form, so probing it must be a loud
-    NotImplementedError — and probe-mode counting services answer
-    correctly while recording no probes for the additive relation."""
+def accumulate_oracle(w: np.ndarray, srcs) -> tuple:
+    """(iterations, rows with a non-zero Δ entering each step, non-zero
+    entries of each new Δ), summed, by NumPy: Δ <- Δ·W until Δ drains."""
+    delta = w[list(srcs)].astype(np.float64)
+    it = active = entries = 0
+    while delta.any():
+        active += int(delta.any(axis=1).sum())
+        delta = delta @ w
+        entries += int(np.count_nonzero(delta))
+        it += 1
+    return it, active, entries
+
+
+def test_additive_counters():
+    """The accumulate form (plus-times) counts the rows whose Δ is non-zero
+    entering each step and the entries of each new Δ — which are the
+    generated facts — on the dense and CSR forms alike, and the service
+    sums them per relation."""
     import jax.numpy as jnp
 
-    from repro.core.semiring import PLUS_TIMES
-    from repro.core.sparse import build_csr
-    from repro.obs.fixpoint_probe import fixpoint_csr_probed, fixpoint_dense_probed
+    from repro.core.seminaive import counts_batch_dense
+    from repro.core.sparse import build_csr, counts_batch_csr
 
-    edges = np.array([[0, 1, 1], [1, 2, 1], [0, 2, 1]], np.int64)
+    edges = np.array([[0, 1, 1], [1, 2, 1], [0, 2, 1], [2, 3, 2],
+                      [1, 3, 1]], np.int64)
     w = np.zeros((8, 8), np.float32)
-    w[edges[:, 0], edges[:, 1]] = 1.0
-    with pytest.raises(NotImplementedError):
-        fixpoint_dense_probed(PLUS_TIMES, jnp.asarray(w), jnp.asarray(w[:1]))
-    with pytest.raises(NotImplementedError):
-        fixpoint_csr_probed(build_csr(edges, 8, "plustimes"),
-                            jnp.zeros((1, 8), jnp.float32))
+    w[edges[:, 0], edges[:, 1]] = edges[:, 2]
+    srcs = [0, 1, 5]
+    want = accumulate_oracle(w, srcs)
+    for res in (counts_batch_dense(jnp.asarray(w), srcs),
+                counts_batch_csr(build_csr(edges, 8, "plustimes"), srcs)):
+        got = (int(res.iterations), int(res.active_rows),
+               int(res.delta_facts))
+        assert got == want
+        assert int(res.generated) == int(res.delta_facts)
     cpath = ("cpath(X,Z,sum<C>) <- d(X,Z,C).\n"
              "cpath(X,Z,sum<C>) <- cpath(X,Y,C1), d(Y,Z,C2), C = C1 * C2.")
-    svc = DatalogService(cpath, db={"d": edges}, probe=True)
+    svc = DatalogService(cpath, db={"d": edges})
     rows, vals = svc.ask("cpath", (0, None, None))
     assert {(int(r[1]), int(v)) for r, v in zip(rows, vals)} == \
-        {(1, 1), (2, 2)}
-    assert not svc.last_probes, "additive batches must run unprobed"
+        {(1, 1), (2, 2), (3, 5)}
+    fx = svc.explain()["relations"]["cpath"]["fixpoint"]
+    it, active, entries = accumulate_oracle(w, [0])
+    assert (fx["launches"], fx["iterations"]) == (1, it)
+    # the pad rows of the launch are all-zero: never live
+    assert (fx["active_rows"], fx["delta_facts"]) == (active, entries)
+
+
+def test_named_scopes_in_compiled_fixpoint():
+    """The seed build, the segment step and the convergence test carry
+    stable names into the compiled program's op metadata, so a device
+    trace can group kernel time by scope."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import sparse as sp
+
+    csr = sp.build_csr(np.asarray([[i, i + 1] for i in range(15)]), 16)
+    init = jax.ShapeDtypeStruct((8, 16), jnp.bool_)
+    ops = re.findall(r'op_name="([^"]*)"',
+                     sp.lower_fixpoint_csr(csr, init).compile().as_text())
+    assert any("/while/body/csr_segment_step/" in o for o in ops)
+    assert any("/while/body/fixpoint_converge/" in o for o in ops)
+    seed = sp._rows_from_sources_jit.lower(csr, jnp.zeros(8, jnp.int32))
+    assert any("/csr_seed/" in o for o in re.findall(
+        r'op_name="([^"]*)"', seed.compile().as_text()))
+
+
+def test_tracing_off_builds_no_flush_record(monkeypatch):
+    """With the no-op tracer the admission flush opens only the shared
+    no-op span and annotates nothing, so no per-query wait list is built,
+    no flush is numbered and no lock is taken for the in-flight count;
+    the queue-wait histogram still sees every query."""
+    from repro.obs import trace
+
+    class Recorder:
+        """The shared no-op span, recording what it was handed."""
+
+        def __init__(self):
+            self.annotated = []
+
+        def annotate(self, **kv):
+            self.annotated.append(kv)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+        def end(self):
+            pass
+
+    rec, opened = Recorder(), []
+
+    def span(name, cat="service", **args):
+        opened.append((name, args))
+        return rec
+
+    made = []
+    real_init = trace.Span.__init__
+
+    def counting_init(self, *a, **kw):
+        made.append(a[0] if a else None)
+        real_init(self, *a, **kw)
+
+    monkeypatch.setattr(NULL_TRACER, "span", span)
+    monkeypatch.setattr(trace.Span, "__init__", counting_init)
+    svc = DatalogService(TC, db={"arc": ring(24)}, default_cap=4096)
+    assert svc.tracer is NULL_TRACER
+    front = AsyncDatalogService(svc, max_wait_ms=1.0, max_batch=4)
+    for f in [front.submit(f"tc({s}, X)") for s in range(6)]:
+        f.result(timeout=120)
+    front.close()
+    assert not made, "no span object may be created with tracing off"
+    # the coalescing window's batch size is the only annotation
+    assert rec.annotated and all(kv.keys() == {"batch"} for kv in rec.annotated)
+    flushes = [args for name, args in opened if name == "flush"]
+    assert flushes and all(args == {} for args in flushes)
+    assert all(args.get("flush") is None and args.get("ahead") is None
+               for name, args in opened if name == "launch_batch")
+    assert svc._flush_seq == 0 and svc._unsynced == 0
+    assert svc.metrics.histogram("datalog_queue_wait_seconds").count() == 6
+
+
+def test_flush_numbering_under_racing_threads():
+    """The in-flight count is shared by the dispatcher (launch) and the
+    finalizer (sync): under many submitters and a short switch interval
+    every flush gets its own number and the count returns to zero."""
+    import sys
+
+    svc = DatalogService(TC, db={"arc": ring(40)}, default_cap=4096,
+                         tracer=True, batch_pads=(4,))
+    front = AsyncDatalogService(svc, max_wait_ms=0.5, max_batch=4,
+                                inflight=2)
+    futs, lock = [], threading.Lock()
+
+    def submit(k):
+        for s in range(k, 40, 8):
+            f = front.submit(f"tc({s}, X)")
+            with lock:
+                futs.append(f)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=submit, args=(k,)) for k in range(8)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in ts)
+        for f in futs:
+            f.result(timeout=120)
+        front.close()
+    finally:
+        sys.setswitchinterval(old)
+    launches = svc.tracer.spans("launch_batch")
+    seqs = [e["args"]["flush"] for e in launches]
+    assert sorted(seqs) == list(range(1, len(launches) + 1))
+    assert svc._unsynced == 0 and svc._flush_seq == len(launches)
+    assert front.stats.flushes == len(launches)
